@@ -97,6 +97,8 @@ def main(argv=None) -> None:
     if row_timeout is None:
         row_timeout = SMOKE_ROW_TIMEOUT_S if args.smoke else 0.0
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import common
     if args.smoke:
         common.enable_smoke()

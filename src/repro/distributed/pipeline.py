@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -56,7 +56,7 @@ def pipelined_forward(layer_fn: Callable, params_stacked, x,
         in_specs=(jax.tree.map(lambda _: P(stage_axis), params_staged),
                   P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params_local, x_local):
         # params_local: (1, L/S, ...); x_local: full batch (replicated)
         stage_params = jax.tree.map(lambda p: p[0], params_local)

@@ -13,10 +13,17 @@ This module turns those into tuned, per-shape choices:
 3. **Optional measurement** — on real hardware, pass ``measure`` (a callable
    ``blocks -> seconds``) to time the surviving top-k and pick the winner;
    without it (this CPU container) the roofline argmin is used directly.
-4. **Persistence** — winners land in a versioned JSON cache keyed by
-   ``(kernel, shape-bucket, device-kind)`` so later processes (and the
-   kernels' public entry points, which consult :func:`best_config` when
-   called without explicit blocks) skip the sweep.
+4. **Persistence** — winners are memoized per process, keyed by
+   ``(kernel, shape-bucket, device-kind)``; the kernels' public entry
+   points consult :func:`best_config` when called without explicit
+   blocks. ``REPRO_AUTOTUNE_CACHE`` names a versioned JSON file that
+   persists them across processes; unset, nothing is read or written, so
+   block choices come only from this code and never from a file outside
+   the checkout.
+
+The roofline target is the chip JAX runs on: on a TPU backend it is looked
+up by ``device_kind`` (:func:`repro.roofline.hw.chip_for_device_kind`,
+which raises for a kind it does not know); elsewhere it is the v5e.
 
 The same machinery hosts the engine-level *batch-size* selection the
 roadmap calls for (`roofline-verified batch-size selection per app`):
@@ -41,7 +48,7 @@ import os
 import threading
 from typing import Callable, Optional
 
-from repro.roofline.hw import ChipSpec, DEFAULT_CHIP
+from repro.roofline.hw import ChipSpec, DEFAULT_CHIP, chip_for_device_kind
 
 SCHEMA_VERSION = 1
 
@@ -59,18 +66,18 @@ _FILE_LOADED = [False]
 
 # --------------------------------------------------------------- cache file
 
-def cache_path() -> str:
-    env = os.environ.get("REPRO_AUTOTUNE_CACHE")
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "autotune.json")
+def cache_path() -> Optional[str]:
+    """The persisted cache file, or None (in-memory only) when
+    ``REPRO_AUTOTUNE_CACHE`` is unset."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or None
 
 
 def _load_file() -> None:
     if _FILE_LOADED[0]:
         return
     _FILE_LOADED[0] = True
+    if cache_path() is None:
+        return
     try:
         with open(cache_path()) as f:
             doc = json.load(f)
@@ -82,6 +89,8 @@ def _load_file() -> None:
 
 def _save_file() -> None:
     path = cache_path()
+    if path is None:
+        return
     try:
         # merge-before-write: another process may have persisted entries
         # (possibly expensive measured-on-TPU ones) since we loaded — keep
@@ -108,7 +117,7 @@ def reset(clear_file: bool = False) -> None:
     with _LOCK:
         _MEM.clear()
         _FILE_LOADED[0] = False
-        if clear_file:
+        if clear_file and cache_path() is not None:
             try:
                 os.remove(cache_path())
             except OSError:
@@ -130,11 +139,17 @@ def pow2_bucket(n: int) -> int:
 
 
 def device_kind() -> str:
-    try:
-        import jax
-        return str(jax.devices()[0].device_kind).replace(" ", "-").lower()
-    except Exception:  # noqa: BLE001 — no backend at all
-        return "unknown"
+    import jax
+    return str(jax.devices()[0].device_kind).replace(" ", "-").lower()
+
+
+def target_chip() -> ChipSpec:
+    """Roofline target: the attached TPU by its ``device_kind`` (an
+    unknown kind raises), or the v5e target on any other backend."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return DEFAULT_CHIP
+    return chip_for_device_kind(jax.devices()[0].device_kind)
 
 
 def _key(kernel: str, bucket: dict, chip: ChipSpec) -> str:
@@ -295,7 +310,7 @@ def _engine_chunk_roofline(bk: dict, blocks: dict, chip: ChipSpec) -> float:
     return imbalance + 1e-6 * c          # tie-break toward lower stall
 
 
-def engine_prefill_chunk(cfg, *, chip: ChipSpec = DEFAULT_CHIP,
+def engine_prefill_chunk(cfg, *, chip: Optional[ChipSpec] = None,
                          max_seq: int = 4096) -> int:
     """Autotuned prefill-chunk size for serving ``cfg`` on ``chip``.
 
@@ -350,9 +365,10 @@ def _ssd_bucket(shape: dict) -> dict:
 
 
 def _ssd_candidates(bk: dict) -> list[dict]:
+    # the head block tiles dt/cum's second-minor dim, which the TPU lowering
+    # takes only as a multiple of 8 or as the whole head axis
     h = bk["h"]
-    cands = sorted({largest_divisor(h, c) for c in (1, 2, 4, 8, 16, 32)
-                    if c <= h})
+    cands = [hb for hb in (8, 16, 32) if h % hb == 0] or [h]
     return [{"head_block": hb} for hb in cands]
 
 
@@ -405,7 +421,8 @@ def candidates(kernel: str, shape: dict) -> list[dict]:
     return cands or cand_fn(bk)[:1]   # degenerate shape: keep one candidate
 
 
-def best_config(kernel: str, shape: dict, *, chip: ChipSpec = DEFAULT_CHIP,
+def best_config(kernel: str, shape: dict, *,
+                chip: Optional[ChipSpec] = None,
                 measure: Optional[Callable[[dict], float]] = None,
                 top_k: int = 3) -> dict:
     """Best block config for ``kernel`` on ``shape``.
@@ -413,10 +430,12 @@ def best_config(kernel: str, shape: dict, *, chip: ChipSpec = DEFAULT_CHIP,
     Returns the block dict (e.g. ``{"s_block": 512}``). Consults the
     in-memory + JSON caches first; otherwise sweeps candidates, prunes with
     the roofline model, optionally times the survivors via ``measure``
-    (``blocks -> seconds``), and persists the winner.
+    (``blocks -> seconds``), and persists the winner. ``chip=None`` targets
+    :func:`target_chip`.
     """
     if kernel not in _KERNELS:
         raise KeyError(f"unknown kernel {kernel!r}; known: {sorted(_KERNELS)}")
+    chip = chip or target_chip()
     bucket_fn = _KERNELS[kernel][0]
     key = _key(kernel, bucket_fn(shape), chip)
     with _LOCK:

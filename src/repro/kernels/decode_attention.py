@@ -36,7 +36,9 @@ def _rope_rotate(q, position, theta: float):
     """Rotate (G, d) query rows to ``position`` (scalar int32) in-kernel."""
     g, d = q.shape
     half = d // 2
-    idx = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+    # Mosaic's iota is integer-only: build the index in int32, then cast
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+        jnp.float32)
     inv = jnp.exp(idx * (-2.0 / d) * math.log(theta))        # theta^(-2i/d)
     ang = position.astype(jnp.float32) * inv                 # (1, half)
     sin = jnp.sin(ang)

@@ -76,7 +76,7 @@ def attention_decode(q, k_cache, v_cache, lengths, rope_theta=None):
 
 def attention_decode_paged(q, k_pages, v_pages, block_tables, lengths,
                            rope_theta=None):
-    """q: (B, 1, H, d); pools: (P, page, KV, d); block_tables: (B, nb);
+    """q: (B, 1, H, d); pools: (P, KV, page, d); block_tables: (B, nb);
     lengths (B,) -> (B, 1, H, d).
 
     Paged counterpart of :func:`attention_decode`: K/V are gathered through
@@ -124,7 +124,7 @@ def attention_prefill_chunk(q, k_cache, v_cache, start_len, rope_theta=None):
 
 def attention_prefill_chunk_paged(q, k_pages, v_pages, block_tables,
                                   start_len, rope_theta=None):
-    """q: (B, C, H, d) UN-rotated; pools: (P, page, KV, d); block_tables:
+    """q: (B, C, H, d) UN-rotated; pools: (P, KV, page, d); block_tables:
     (B, nb); start_len: (B,) -> (B, C, H, d).
 
     Paged counterpart of :func:`attention_prefill_chunk`: K/V are gathered
@@ -132,12 +132,10 @@ def attention_prefill_chunk_paged(q, k_pages, v_pages, block_tables,
     materialized gather on jnp). Same fused-RoPE contract."""
     be = backend()
     if be == "jnp":
-        from repro.models.attention import prefill_chunk_attention_jnp
-        k = k_pages[block_tables]              # (B, nb, page, KV, d)
-        v = v_pages[block_tables]
-        b, nb, page, kv, d = k.shape
-        k = k.reshape(b, nb * page, kv, d)
-        v = v.reshape(b, nb * page, kv, d)
+        from repro.models.attention import (gather_pages,
+                                            prefill_chunk_attention_jnp)
+        k = gather_pages(k_pages, block_tables)
+        v = gather_pages(v_pages, block_tables)
         positions = jnp.asarray(start_len)[:, None] + \
             jnp.arange(q.shape[1])[None, :]
         return prefill_chunk_attention_jnp(q, k, v, positions,

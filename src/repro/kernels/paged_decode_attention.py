@@ -1,7 +1,7 @@
 """Pallas TPU paged flash-decode: one query token against a PAGED KV cache.
 
 Same online-softmax flash-decode as :mod:`repro.kernels.decode_attention`,
-but K/V live in a shared page pool ``(P, page_size, KV, d)`` instead of one
+but K/V live in a shared page pool ``(P, KV, page_size, d)`` instead of one
 contiguous ``(B, KV, S, d)`` cache, and each batch row reads its pages
 through a block table ``(B, nb)`` of page ids. The gather is free: the
 block table is a scalar-prefetch operand (SMEM), so the BlockSpec index map
@@ -26,9 +26,12 @@ itself — per-grid-step issue overhead pushes pages up, internal
 fragmentation (half a page wasted per sequence on average) pushes them
 down — and the engine consults it when constructing the pool.
 
-Layout: q (B, H, d); k/v pools (P, page_size, KV, d) — the MODEL layout,
+Layout: q (B, H, d); k/v pools (P, KV, page_size, d) — the MODEL layout,
 consumed directly so no caller ever relayouts the (large) pool on the
-decode hot path; block_tables (B, nb) int32; lengths (B,) int32.
+decode hot path. Head-major pages keep the streamed tile's last two dims
+at ``(page_size, d)``, the whole trailing extent of the pool, which is what
+the TPU lowering accepts for a one-head slab; block_tables (B, nb) int32;
+lengths (B,) int32.
 """
 from __future__ import annotations
 
@@ -65,7 +68,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         if rope_theta is not None:
             q = _rope_rotate(q, length - 1, rope_theta)
         q = q * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (page, d)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -75,7 +78,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (page, d)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (page, d)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
@@ -91,14 +94,14 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            rope_theta: float | None = None,
                            interpret: bool = False):
-    """q: (B, H, d); k/v pools: (P, page, KV, d) — the model layout, read
+    """q: (B, H, d); k/v pools: (P, KV, page, d) — the model layout, read
     in place (no pool-wide relayout on the hot path); block_tables:
     (B, nb) int32 page ids; lengths: (B,) -> (B, H, d).
 
     ``rope_theta``: fuse rotary embedding of q at position ``lengths - 1``.
     """
     b, h, d = q.shape
-    page, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page = k_pages.shape[1], k_pages.shape[2]
     g = h // kv
     nb = block_tables.shape[1]
     scale = 1.0 / math.sqrt(d)
@@ -113,12 +116,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
             pl.BlockSpec((1, 1, g, d), lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
             # the paged gather: the tile for grid step (b, k, j) is the
             # row's j-th page, resolved from the prefetched block table;
-            # the (page, 1, d) slab picks head k_ out of the model-layout
-            # pool so only owned pages ever move
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
+            # the (page, d) slab of head k_ is contiguous in the pool, so
+            # only owned pages ever move
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),

@@ -1,7 +1,7 @@
 """Pallas TPU paged prefill-chunk flash attention: a chunk vs a PAGED cache.
 
 Same chunk-vs-cache online softmax as :mod:`repro.kernels.prefill_attention`
-with K/V living in the shared page pool ``(P, page_size, KV, d)`` instead of
+with K/V living in the shared page pool ``(P, KV, page_size, d)`` instead of
 a contiguous per-slot cache — the paged counterpart, exactly as
 :mod:`repro.kernels.paged_decode_attention` is to
 :mod:`repro.kernels.decode_attention`. The block table is a scalar-prefetch
@@ -14,7 +14,7 @@ sit beyond the row's causal horizon ``start_len + r//G`` and are masked by
 the online softmax. Rotary embedding of row r's query is fused at absolute
 position ``start_len + r//G`` (cached keys are rotated at write time).
 
-Layout: q (B, H, C, d) head-major; k/v pools (P, page_size, KV, d) — the
+Layout: q (B, H, C, d) head-major; k/v pools (P, KV, page_size, d) — the
 MODEL layout, read in place; block_tables (B, nb) int32; start_len (B,).
 """
 from __future__ import annotations
@@ -54,7 +54,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         if rope_theta is not None:
             q = _rope_rotate_rows(q, qpos, rope_theta)
         q = q * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (page, d)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -64,7 +64,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (page, d)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (page, d)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
@@ -80,7 +80,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
                             rope_theta: float | None = None,
                             interpret: bool = False):
-    """q: (B, H, C, d); k/v pools: (P, page, KV, d) read in place, the
+    """q: (B, H, C, d); k/v pools: (P, KV, page, d) read in place, the
     chunk's keys/values already scattered into the rows' pages;
     block_tables: (B, nb) int32 page ids; start_len: (B,) -> (B, H, C, d).
 
@@ -88,7 +88,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
     position ``start_len + j``.
     """
     b, h, c, d = q.shape
-    page, kv = k_pages.shape[1], k_pages.shape[2]
+    kv, page = k_pages.shape[1], k_pages.shape[2]
     g = h // kv
     nb = block_tables.shape[1]
     scale = 1.0 / math.sqrt(d)
@@ -106,10 +106,10 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
                          lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
             # the paged gather: grid step (b, k, j) streams the row's j-th
             # page, resolved from the prefetched block table
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], 0, k_, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
+            pl.BlockSpec((1, 1, page, d),
+                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, c * g, d),
                                lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),

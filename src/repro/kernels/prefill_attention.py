@@ -46,7 +46,9 @@ def _rope_rotate_rows(q, positions, theta: float):
     """Rotate (R, d) query rows, row r at ``positions[r]`` ((R, 1) int32)."""
     r, d = q.shape
     half = d // 2
-    idx = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+    # Mosaic's iota is integer-only: build the index in int32, then cast
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+        jnp.float32)
     inv = jnp.exp(idx * (-2.0 / d) * math.log(theta))        # theta^(-2i/d)
     ang = positions.astype(jnp.float32) * inv                # (R, half)
     sin = jnp.sin(ang)

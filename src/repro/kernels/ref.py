@@ -67,18 +67,22 @@ def paged_decode_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                rope_theta: float | None = None) -> Array:
     """Paged flash-decode oracle: gather pages, defer to the dense oracle.
 
-    q: (B, H, d); k/v pools: (P, page, KV, d) — the kernel's model layout;
+    q: (B, H, d); k/v pools: (P, KV, page, d) — the kernel's model layout;
     block_tables: (B, nb) int32 page ids; lengths: (B,). -> (B, H, d).
     Unallocated table entries hold a valid sentinel page; its stale
     contents sit past ``lengths`` and are masked, so the
     gather-then-attend is exact.
     """
-    k = k_pages[block_tables]                       # (B, nb, page, KV, d)
-    v = v_pages[block_tables]
-    b, nb, page, kv, d = k.shape
-    k = k.reshape(b, nb * page, kv, d).transpose(0, 2, 1, 3)
-    v = v.reshape(b, nb * page, kv, d).transpose(0, 2, 1, 3)
-    return decode_attention_ref(q, k, v, lengths, rope_theta=rope_theta)
+    return decode_attention_ref(q, _pages_head_major(k_pages, block_tables),
+                                _pages_head_major(v_pages, block_tables),
+                                lengths, rope_theta=rope_theta)
+
+
+def _pages_head_major(pool: Array, block_tables: Array) -> Array:
+    """pool (P, KV, page, d) gathered per row -> (B, KV, nb*page, d)."""
+    g = pool[block_tables]                          # (B, nb, KV, page, d)
+    b, nb, kv, page, d = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(b, kv, nb * page, d)
 
 
 def prefill_attention_ref(q: Array, k: Array, v: Array, start_len: Array,
@@ -115,14 +119,11 @@ def paged_prefill_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                 rope_theta: float | None = None) -> Array:
     """Paged prefill-chunk oracle: gather pages, defer to the dense oracle.
 
-    q: (B, H, C, d); k/v pools: (P, page, KV, d); block_tables: (B, nb)
+    q: (B, H, C, d); k/v pools: (P, KV, page, d); block_tables: (B, nb)
     int32 page ids; start_len: (B,). -> (B, H, C, d)."""
-    k = k_pages[block_tables]                       # (B, nb, page, KV, d)
-    v = v_pages[block_tables]
-    b, nb, page, kv, d = k.shape
-    k = k.reshape(b, nb * page, kv, d).transpose(0, 2, 1, 3)
-    v = v.reshape(b, nb * page, kv, d).transpose(0, 2, 1, 3)
-    return prefill_attention_ref(q, k, v, start_len, rope_theta=rope_theta)
+    return prefill_attention_ref(q, _pages_head_major(k_pages, block_tables),
+                                 _pages_head_major(v_pages, block_tables),
+                                 start_len, rope_theta=rope_theta)
 
 
 def ssd_chunk_ref(x: Array, dt: Array, cum: Array, b_: Array, c_: Array) -> tuple[Array, Array]:

@@ -8,7 +8,10 @@ stays inside VMEM; Q and the head block are MXU/VPU aligned.
 
 Layouts: x (M, Q, H, P); dt/cum (M, Q, H); b_/c_ (M, Q, N)
 with M = batch*num_chunks flattened. Outputs: y (M, Q, H, P),
-state (M, H, P, N).
+state (M, H, P, N). The wrapper hands the kernel x, dt, cum and y
+head-major ((M, H, Q, P) and (M, H, Q)): a head block then tiles a major
+or second-minor dim, where the TPU lowering takes any multiple of 8, and
+each head's (Q, P) / (1, Q) slab is a whole tile, never a strided slice.
 """
 from __future__ import annotations
 
@@ -24,9 +27,8 @@ from repro.kernels.autotune import largest_divisor as _largest_divisor
 
 def _kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *,
             q: int, hb: int, p: int, n: int):
-    x = x_ref[0].astype(jnp.float32)            # (Q, hb, P)
-    dt = dt_ref[0].astype(jnp.float32)          # (Q, hb)
-    cum = cum_ref[0].astype(jnp.float32)        # (Q, hb)
+    dt = dt_ref[0].astype(jnp.float32)          # (hb, Q)
+    cum = cum_ref[0].astype(jnp.float32)        # (hb, Q)
     b_ = b_ref[0].astype(jnp.float32)           # (Q, N)
     c_ = c_ref[0].astype(jnp.float32)           # (Q, N)
 
@@ -37,15 +39,17 @@ def _kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *,
     tri = row >= col
 
     for h in range(hb):  # static unroll over the head block
-        seg = cum[:, h][:, None] - cum[:, h][None, :]          # (Q, Q)
+        cum_h = cum[h:h + 1]                                   # (1, Q)
+        dt_h = dt[h:h + 1]                                     # (1, Q)
+        seg = cum_h.T - cum_h                                  # (Q, Q)
         decay = jnp.where(tri, jnp.exp(seg), 0.0)
-        scores = cb * decay * dt[:, h][None, :]                # (Q, Q)
-        xh = x[:, h]                                           # (Q, P)
+        scores = cb * decay * dt_h                             # (Q, Q)
+        xh = x_ref[0, h].astype(jnp.float32)                   # (Q, P)
         y = jax.lax.dot_general(scores, xh, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        y_ref[0, :, h, :] = y.astype(y_ref.dtype)
-        wgt = jnp.exp(cum[-1, h] - cum[:, h]) * dt[:, h]       # (Q,)
-        xw = xh * wgt[:, None]                                 # (Q, P)
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        wgt = jnp.exp(cum[h:h + 1, q - 1:q] - cum_h) * dt_h    # (1, Q)
+        xw = xh * wgt.T                                        # (Q, P)
         st = jax.lax.dot_general(xw, b_, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (P, N)
         st_ref[0, h] = st.astype(st_ref.dtype)
@@ -75,20 +79,21 @@ def ssd_chunk_scan(x, dt, cum, b_, c_, *, head_block: int | None = None,
         kernel,
         grid=(m, nh),
         in_specs=[
-            pl.BlockSpec((1, q, hb, p), lambda i, j: (i, 0, j, 0)),
-            pl.BlockSpec((1, q, hb), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, q, hb), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, hb, q, p), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, hb, q), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, hb, q), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, q, n), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, q, n), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q, hb, p), lambda i, j: (i, 0, j, 0)),
+            pl.BlockSpec((1, hb, q, p), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, hb, p, n), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, q, h, p), x.dtype),
+            jax.ShapeDtypeStruct((m, h, q, p), x.dtype),
             jax.ShapeDtypeStruct((m, h, p, n), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, cum, b_, c_)
-    return y, st
+    )(x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), cum.transpose(0, 2, 1),
+      b_, c_)
+    return y.transpose(0, 2, 1, 3), st

@@ -1,0 +1,28 @@
+"""JAX's persistent compilation cache for this repository's entry points.
+
+Entry points (``repro.launch.serve``, ``benchmarks/run.py``,
+``chip_smoke.py``) call :func:`enable_compile_cache` once, before their
+first compile; importing this module changes nothing. Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this code sets
+no other directory. Otherwise the cache lives at ``<checkout>/.jax_cache``:
+a fixed path, because the path is part of what a later run must find again.
+"""
+from __future__ import annotations
+
+import os
+import pathlib
+
+import jax
+
+#: the repository root (``src/repro/launch/`` is three levels below it)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache; returns its directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

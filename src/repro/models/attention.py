@@ -162,15 +162,24 @@ def prefill_chunk_attention_jnp(q: Array, k_full: Array, v_full: Array,
     return o.reshape(b, c, h, d)
 
 
+def gather_pages(pool: Array, block_tables: Array) -> Array:
+    """Contiguous per-row view of a paged pool (the jnp lowering's
+    materialized gather). pool: (P, KV, page, d) head-major model layout;
+    block_tables: (B, nb) int32 page ids -> (B, nb*page, KV, d)."""
+    g = pool[block_tables]                     # (B, nb, KV, page, d)
+    b, nb, kv, page, d = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, nb * page, kv, d)
+
+
 def paged_decode_attention_jnp(q: Array, k_pages: Array, v_pages: Array,
                                block_tables: Array, length: Array,
                                rope_theta: float | None = None) -> Array:
     """Single-token decode attention against a PAGED cache (jnp lowering).
 
-    q: (B, 1, H, d); pools: (P, page, KV, d) model layout; block_tables:
+    q: (B, 1, H, d); pools: (P, KV, page, d) model layout; block_tables:
     (B, nb) int32 page ids; length: (B,) valid prefix per row.
 
-    The jnp fallback materializes the gathered view ``pool[block_tables]``
+    The jnp fallback materializes the gathered view (:func:`gather_pages`)
     and defers to :func:`decode_attention_jnp` — correct everywhere, and
     cheap at CPU test shapes. The Pallas kernel
     (``repro.kernels.paged_decode_attention``) is the TPU runtime path that
@@ -178,11 +187,8 @@ def paged_decode_attention_jnp(q: Array, k_pages: Array, v_pages: Array,
     Sentinel (unallocated) table entries point at a real page whose stale
     contents lie beyond ``length`` — masked like cache padding.
     """
-    k = k_pages[block_tables]                  # (B, nb, page, KV, d)
-    v = v_pages[block_tables]
-    b, nb, page, kv, d = k.shape
-    k = k.reshape(b, nb * page, kv, d)
-    v = v.reshape(b, nb * page, kv, d)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     return decode_attention_jnp(q, k, v, length, rope_theta=rope_theta)
 
 

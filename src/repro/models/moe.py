@@ -117,13 +117,8 @@ def moe_ffn(params: dict, x: Array, cfg: ModelConfig,
 # whole (E, C, D) buffer (measured: 37 TB/chip for kimi prefill_32k).
 
 def _ambient_mesh_axes():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if mesh is None or not mesh.axis_names:
-        return None
-    return mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 def moe_ffn_shardmap(params: dict, x: Array, cfg: ModelConfig):
@@ -213,6 +208,11 @@ def moe_ffn_shardmap(params: dict, x: Array, cfg: ModelConfig):
         check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
 
+    if jax.sharding.AxisType.Explicit in mesh.axis_types:
+        # explicit mesh axes (jax.make_mesh's default) put the out_specs'
+        # batch sharding into y's type; hand back the input's type so the
+        # layer scan's residual carry keeps one type across layers
+        y = jax.sharding.reshard(y, jax.typeof(x).sharding.spec)
     if "shared" in params:
         y = y + layers.mlp(params["shared"], x)
     return y, aux

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers, moe
 from repro.models.attention import (decode_attention_jnp, flash_attention_jnp,
-                                    naive_attention,
+                                    gather_pages, naive_attention,
                                     prefill_chunk_attention_jnp)
 
 Array = jax.Array
@@ -200,7 +200,7 @@ def attention_decode_block_paged(p: dict, x: Array, cfg: ModelConfig,
                                  active: Array | None = None):
     """One-token attention against a PAGED cache.
 
-    x: (B,1,D); pools: (P, page, KV, hd) shared across rows; block_tables:
+    x: (B,1,D); pools: (P, KV, page, hd) shared across rows; block_tables:
     (B, nb) int32 page ids. The new k/v lands in the page covering position
     ``lengths`` (the engine maps that page before dispatch); attention
     gathers K/V through the block table (``ops.attention_decode_paged`` —
@@ -215,15 +215,16 @@ def attention_decode_block_paged(p: dict, x: Array, cfg: ModelConfig,
     positions = lengths[:, None]
     q, k, v = _project_qkv(p, x, cfg, positions, rope_q=False)
 
-    num_pages, page = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page = k_pages.shape[0], k_pages.shape[2]
     block = jnp.minimum(lengths // page, block_tables.shape[1] - 1)
     pidx = jnp.take_along_axis(block_tables, block[:, None], axis=1)[:, 0]
     off = lengths % page
     if active is not None:
         pidx = jnp.where(active, pidx, jnp.int32(num_pages))  # drop writes
-    k_pages = k_pages.at[pidx, off].set(
+    # (B,) page ids and offsets around the head axis index (B, KV, hd)
+    k_pages = k_pages.at[pidx, :, off].set(
         k[:, 0].astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[pidx, off].set(
+    v_pages = v_pages.at[pidx, :, off].set(
         v[:, 0].astype(v_pages.dtype), mode="drop")
     from repro.kernels import ops
     o = ops.attention_decode_paged(q, k_pages, v_pages, block_tables,
@@ -262,7 +263,7 @@ def attention_prefill_chunk_block_paged(p: dict, x: Array, cfg: ModelConfig,
     :func:`attention_prefill_chunk_block` with the cache paged (pad-token
     page ids pushed past the pool end under ``valid``)."""
     b, c, _ = x.shape
-    num_pages, page = k_pages.shape[0], k_pages.shape[1]
+    num_pages, page = k_pages.shape[0], k_pages.shape[2]
     nb = block_tables.shape[1]
     positions = start_len[:, None] + jnp.arange(c)[None, :]       # (B,C)
     q, k, v = _project_qkv(p, x, cfg, positions, rope_q=False)
@@ -275,8 +276,10 @@ def attention_prefill_chunk_block_paged(p: dict, x: Array, cfg: ModelConfig,
     if valid is not None:
         tok_ok = jnp.arange(c)[None, :] < valid[:, None]          # (B,C)
         pidx = jnp.where(tok_ok, pidx, jnp.int32(num_pages))
-    k_pages = k_pages.at[pidx, off].set(k.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[pidx, off].set(v.astype(v_pages.dtype), mode="drop")
+    k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype),
+                                           mode="drop")
+    v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype),
+                                           mode="drop")
 
     from repro.kernels import ops
     if ops.backend() != "jnp":
@@ -286,8 +289,8 @@ def attention_prefill_chunk_block_paged(p: dict, x: Array, cfg: ModelConfig,
                                               rope_theta=cfg.rope_theta)
         out = jnp.einsum("bshe,hed->bsd", o.astype(x.dtype), p["wo"])
         return out, (k_pages, v_pages)
-    k_full = k_pages[block_tables].reshape(b, nb * page, *k_pages.shape[2:])
-    v_full = v_pages[block_tables].reshape(b, nb * page, *v_pages.shape[2:])
+    k_full = gather_pages(k_pages, block_tables)
+    v_full = gather_pages(v_pages, block_tables)
     out = _chunk_attend(p, q, k_full, v_full, positions, cfg, x.dtype)
     return out, (k_pages, v_pages)
 
@@ -440,14 +443,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> dict:
     """Page-pool KV cache: ``num_pages`` shared pages of ``page_size``
-    tokens per layer; rows address them through engine-side block tables.
-    No int8 variant — the engine keeps the contiguous cache under
-    ``kv_cache_dtype`` hints."""
+    tokens per layer, head-major ``(L, P, KV, page, hd)`` so a page's
+    per-head ``(page, hd)`` slab is one tile of the paged kernels; rows
+    address pages through engine-side block tables. No int8 variant — the
+    engine keeps the contiguous cache under ``kv_cache_dtype`` hints."""
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     l = cfg.num_layers
     return {
-        "k_pages": jnp.zeros((l, num_pages, page_size, kv, hd), dtype),
-        "v_pages": jnp.zeros((l, num_pages, page_size, kv, hd), dtype),
+        "k_pages": jnp.zeros((l, num_pages, kv, page_size, hd), dtype),
+        "v_pages": jnp.zeros((l, num_pages, kv, page_size, hd), dtype),
     }
 
 

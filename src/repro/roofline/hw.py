@@ -1,8 +1,9 @@
-"""Target-hardware constants (TPU v5e) for the analytic roofline.
+"""Target-hardware constants for the analytic roofline.
 
-The container runs on CPU; these constants describe the TARGET the dry-run
-artifacts are analysed against, per the assignment:
-  197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+TPU peaks are Google Cloud's published per-chip figures (Cloud TPU docs,
+"TPU v5e" and "TPU v5p"): v5e 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s;
+v5p 459 TFLOP/s bf16, 95 GB HBM at 2,765 GB/s. ``DEVICE_KINDS`` maps the
+``device_kind`` JAX reports to these specs.
 """
 from __future__ import annotations
 
@@ -73,6 +74,19 @@ HOST_CPU = ChipSpec(
 DEFAULT_CHIP = TPU_V5E
 
 CHIPS = {c.name: c for c in (TPU_V5E, TPU_V5P, HOST_CPU)}
+
+#: ``jax.devices()[0].device_kind`` -> spec, for the TPUs this table knows
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E, "TPU v5": TPU_V5P}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The spec of a TPU by the ``device_kind`` JAX reports; a kind not
+    in :data:`DEVICE_KINDS` is an error, never a default."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no roofline spec for TPU device kind {kind!r}; "
+                         f"known: {sorted(DEVICE_KINDS)}") from None
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:

@@ -311,7 +311,16 @@ class InferenceEngine:
 
     # ------------------------------------------------------------- setup
     def load_params(self, params):
+        """Serve ``params``. Parameters that all live on one device take
+        the cache with them, so a replica placed with ``jax.device_put``
+        serves entirely from its own device."""
         self.params = params
+        devices = {d for leaf in jax.tree.leaves(params)
+                   for d in leaf.devices()}
+        if len(devices) == 1:
+            (dev,) = devices
+            self.cache = jax.device_put(self.cache, dev)
+            self._fresh_slot = jax.device_put(self._fresh_slot, dev)
 
     def now(self) -> float:
         return self._vclock if self._use_vclock else _time.monotonic() - self._t0

@@ -133,3 +133,43 @@ def test_roofline_batch_size_sane():
     assert best_batch_size(CONFIGS["tinyllama-1.1b"]) >= 8
     assert best_batch_size(CONFIGS["kimi-k2-1t-a32b"]) == 1
     assert best_batch_size(CONFIGS["mamba2-1.3b"]) >= 8
+
+
+def test_best_config_reads_and_writes_no_file_by_default(tmp_path,
+                                                        monkeypatch):
+    """Without REPRO_AUTOTUNE_CACHE the tuner keeps winners in the process:
+    nothing outside the checkout can change which blocks a kernel uses."""
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    autotune.reset()
+    try:
+        assert autotune.cache_path() is None
+        blocks = autotune.best_config(
+            "decode_attention", {"b": 2, "kv": 2, "g": 2, "s": 1024, "d": 64})
+        assert blocks["s_block"] >= 64
+        assert list(tmp_path.rglob("*")) == []
+    finally:
+        autotune.reset()
+
+
+def test_target_chip_off_tpu_is_the_v5e():
+    from repro.roofline.hw import TPU_V5E
+    assert autotune.target_chip() is TPU_V5E
+
+
+def test_chip_for_device_kind():
+    from repro.roofline.hw import TPU_V5E, chip_for_device_kind
+    assert chip_for_device_kind("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        chip_for_device_kind("TPU v9")
+
+
+def test_ssd_head_blocks_tile_the_second_minor_dim():
+    """Head blocks are multiples of 8 (or every head when there are
+    fewer), the only blocks the TPU lowering takes for dt/cum."""
+    for h in (4, 8, 24, 64, 80):
+        for c in autotune.candidates("ssd_chunk_scan",
+                                     {"m": 4, "q": 64, "h": h, "p": 32,
+                                      "n": 64}):
+            hb = c["head_block"]
+            assert h % hb == 0 and (hb % 8 == 0 or hb == h)

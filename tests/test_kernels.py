@@ -311,8 +311,8 @@ def test_paged_prefill_attention(b, h, kv, c, d, page, nb, pool, dtype,
                                  rng_key):
     ks = jax.random.split(rng_key, 5)
     q = jax.random.normal(ks[0], (b, h, c, d), dtype)
-    k_pages = jax.random.normal(ks[1], (pool, page, kv, d), dtype)
-    v_pages = jax.random.normal(ks[2], (pool, page, kv, d), dtype)
+    k_pages = jax.random.normal(ks[1], (pool, kv, page, d), dtype)
+    v_pages = jax.random.normal(ks[2], (pool, kv, page, d), dtype)
     tables = jax.random.randint(ks[3], (b, nb), 0, pool).astype(jnp.int32)
     s = nb * page
     start = jax.random.randint(ks[4], (b,), 0, s - c + 1).astype(jnp.int32)
@@ -332,8 +332,8 @@ def test_paged_prefill_attention_fused_rope(rng_key):
     theta = 10_000.0
     ks = jax.random.split(rng_key, 5)
     q = jax.random.normal(ks[0], (b, h, c, d))
-    k_pages = jax.random.normal(ks[1], (pool, page, kv, d))
-    v_pages = jax.random.normal(ks[2], (pool, page, kv, d))
+    k_pages = jax.random.normal(ks[1], (pool, kv, page, d))
+    v_pages = jax.random.normal(ks[2], (pool, kv, page, d))
     tables = jax.random.randint(ks[3], (b, nb), 0, pool).astype(jnp.int32)
     s = nb * page
     start = jax.random.randint(ks[4], (b,), 0, s - c + 1).astype(jnp.int32)
@@ -344,8 +344,8 @@ def test_paged_prefill_attention_fused_rope(rng_key):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     # dense-oracle cross-check on the gathered view
-    kd = (k_pages[tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3))
-    vd = (v_pages[tables].reshape(b, s, kv, d).transpose(0, 2, 1, 3))
+    kd = k_pages[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, s, d)
+    vd = v_pages[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, s, d)
     dense = ref.prefill_attention_ref(q, kd, vd, start, rope_theta=theta)
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)

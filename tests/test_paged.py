@@ -28,8 +28,8 @@ def test_paged_kernel_matches_oracle(b, h, kv, d, page, nb, rope_theta,
     num_pages = nb * b + 2
     ks = jax.random.split(rng_key, 4)
     q = jax.random.normal(ks[0], (b, h, d))
-    k_pages = jax.random.normal(ks[1], (num_pages, page, kv, d))
-    v_pages = jax.random.normal(ks[2], (num_pages, page, kv, d))
+    k_pages = jax.random.normal(ks[1], (num_pages, kv, page, d))
+    v_pages = jax.random.normal(ks[2], (num_pages, kv, page, d))
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(num_pages)[:b * nb].reshape(b, nb),
                      jnp.int32)
@@ -49,14 +49,14 @@ def test_paged_kernel_ignores_unowned_pages(rng_key):
     b, h, kv, d, page, nb = 1, 4, 2, 32, 16, 4
     ks = jax.random.split(rng_key, 3)
     q = jax.random.normal(ks[0], (b, h, d))
-    k_pages = jax.random.normal(ks[1], (8, page, kv, d))
-    v_pages = jax.random.normal(ks[2], (8, page, kv, d))
+    k_pages = jax.random.normal(ks[1], (8, kv, page, d))
+    v_pages = jax.random.normal(ks[2], (8, kv, page, d))
     bt = jnp.asarray([[3, 5, 0, 0]], jnp.int32)   # tail entries = sentinel
     lengths = jnp.asarray([20], jnp.int32)        # only pages 3,5 valid
     out1 = paged_decode_attention(q, k_pages, v_pages, bt, lengths,
                                   interpret=True)
-    k2 = k_pages.at[0].set(999.0).at[5, 4:].set(-999.0)
-    v2 = v_pages.at[0].set(-999.0).at[5, 4:].set(999.0)
+    k2 = k_pages.at[0].set(999.0).at[5, :, 4:].set(-999.0)
+    v2 = v_pages.at[0].set(-999.0).at[5, :, 4:].set(999.0)
     out2 = paged_decode_attention(q, k2, v2, bt, lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
 
@@ -70,8 +70,8 @@ def test_paged_jnp_fallback_matches_contiguous(rng_key):
     k = jax.random.normal(ks[1], (b, s, kv, d))
     v = jax.random.normal(ks[2], (b, s, kv, d))
     nb = s // page
-    k_pages = k.reshape(b * nb, page, kv, d)
-    v_pages = v.reshape(b * nb, page, kv, d)
+    k_pages = k.reshape(b * nb, page, kv, d).transpose(0, 2, 1, 3)
+    v_pages = v.reshape(b * nb, page, kv, d).transpose(0, 2, 1, 3)
     bt = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     lengths = jnp.asarray([37, 64], jnp.int32)
     got = paged_decode_attention_jnp(q, k_pages, v_pages, bt, lengths,
