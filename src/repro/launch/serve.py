@@ -5,8 +5,10 @@
 
 Parameters are random, made in bf16 from ``--seed`` inside one jitted call,
 so no float32 copy of the model ever exists on the device. The printed
-counts come from the engine; its request timestamps are host-clock stamps
-taken before the device finishes, so no latency is printed here.
+counts come from the engine. Its decode stamps follow the token fetch, but
+prefill stamps only the dispatch, so no latency is printed here; the
+engine's phase spans appear in a ``jax.profiler`` trace taken around
+``main`` (docs/telemetry.md).
 """
 from __future__ import annotations
 

@@ -89,6 +89,28 @@ write into a still-shared page copy-on-write forks it
 (``stats.cow_forks``); pool pressure reclaims cold cached prefixes
 before ever preempting a live slot. Requires a family whose entire
 prefill state is page-resident (``ModelBundle.prefix_shareable``).
+
+Clocks, spans and counters
+--------------------------
+On the wall clock a decode token is stamped (``Request.t_tokens``,
+``t_first_token``, ``t_done``, ``stats.max_decode_gap_s``) after the
+argmax fetch returns, so it times the device's work; the recorder's decode
+spans run from before the decode dispatch to after the fetch. Prefill never
+syncs: ``Request.t_prefill`` and the recorder's prefill spans stamp the
+dispatch, and the device time is in the profiler trace.
+
+Each phase of :meth:`InferenceEngine.step` runs inside a
+``jax.profiler`` span on the profiler's own clock: ``engine.step``
+(a step annotation numbered by ``stats.steps``) around ``engine.admit``,
+one ``engine.prefill`` per prefill dispatch, ``engine.decode`` (page
+growth through the decode dispatch), ``engine.sample`` (the argmax fetch)
+and ``engine.retire``. Outside a trace each span costs one check. The
+jitted programs carry the names of what they run (``decode_step``,
+``prefill_chunk_paged``, ...). ``EngineStats`` counts, always on,
+``host_gap_s``/``host_gaps`` (host time from a token fetch after which work
+remains to the next device dispatch), ``prefill_row_tokens`` (rows times
+width of every prefill dispatch, against the live ``prefill_tokens``) and
+``kv_live_tokens``/``kv_pool_tokens`` (summed at each decode dispatch).
 """
 from __future__ import annotations
 
@@ -100,6 +122,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.bench.policy import SchedulingPolicy, get_policy
 from repro.models.factory import ModelBundle
@@ -129,6 +152,12 @@ class EngineStats:
     mixed_steps: int = 0          # steps advancing BOTH prefill and decode
     decode_ready_time_s: float = 0.0  # phase time with decode rows ready
     decode_stall_time_s: float = 0.0  # ...of which no decode happened
+    # ---- host and device use (always on; read by the chip benchmark)
+    host_gap_s: float = 0.0       # token fetch (work left) -> next dispatch
+    host_gaps: int = 0            # ...how many such gaps closed
+    prefill_row_tokens: int = 0   # rows x width of every prefill dispatch
+    kv_live_tokens: int = 0       # tokens live slots hold, per decode dispatch
+    kv_pool_tokens: int = 0       # the pool's capacity, per decode dispatch
 
 
 class InferenceEngine:
@@ -191,6 +220,9 @@ class InferenceEngine:
         self._t0 = _time.monotonic()
         self.stats = EngineStats()
         self._last_decode_t: Optional[float] = None
+        #: perf_counter() when the last token fetch returned with work left;
+        #: the next device dispatch closes the host gap it opened
+        self._gap_from: Optional[float] = None
 
         # paged by default wherever the family supports it (parity with the
         # contiguous path is pinned per family, so paging is now the engine
@@ -259,6 +291,8 @@ class InferenceEngine:
             self.kv_pages = kv_pages
             self.cache = self.model.init_cache(max_slots, max_seq)
             self._fresh_slot = self.model.init_cache(1, max_seq)
+        self._pool_tokens = (self.kv_pages * self.page_size if paged
+                             else max_slots * max_seq)
         # host mirror: no device sync ever needed to READ a slot's length.
         # COPY-ON-WRITE invariant: jnp.asarray may zero-copy ALIAS this
         # buffer on the CPU backend while dispatch is async, so any buffer
@@ -275,33 +309,24 @@ class InferenceEngine:
         self._eff: dict[int, np.ndarray] = {}
         self.done: list[Request] = []
         # jitted fast paths (eager dispatch would compile thousands of tiny
-        # executables over a serving session and exhaust the CPU ORC JIT);
-        # shared across engines of the same ModelBundle so multiple engines
-        # (or an engine plus its serve-alone test oracle) reuse executables
+        # executables over a serving session and exhaust the CPU ORC JIT),
+        # each named after the model method it runs so a profiler trace
+        # tells them apart; shared across engines of the same ModelBundle
+        # so multiple engines (or an engine plus its serve-alone test
+        # oracle) reuse executables
         jits = getattr(model, "_serving_jit_cache", None)
         if jits is None:
-            jits = {
-                "decode": jax.jit(
-                    lambda p, c, t, ln, act: model.decode_step(p, c, t, ln,
-                                                               act)),
-                "prefill": jax.jit(
-                    lambda p, c, t, st, act, val: model.prefill_chunk(
-                        p, c, t, st, act, val)),
-                "decode_paged": jax.jit(
-                    lambda p, c, t, ln, bt, act: model.decode_step_paged(
-                        p, c, t, ln, bt, act)),
-                "prefill_paged": jax.jit(
-                    lambda p, c, t, st, bt, act, val:
-                        model.prefill_chunk_paged(p, c, t, st, bt, act,
-                                                  val)),
+            jits = model._serving_jit_cache = {
+                "decode": jax.jit(model.decode_step),
+                "prefill": jax.jit(model.prefill_chunk),
+                "decode_paged": jax.jit(model.decode_step_paged),
+                "prefill_paged": jax.jit(model.prefill_chunk_paged),
                 "set_slice": jax.jit(model.set_cache_slice,
                                      static_argnums=(1,)),
                 # CoW fork: page ids stay traced — ONE executable serves
                 # every fork of this model's pool
-                "copy_page": jax.jit(
-                    lambda c, s, d: model.copy_page(c, s, d)),
+                "copy_page": jax.jit(model.copy_page),
             }
-            model._serving_jit_cache = jits
         self._jit_decode = jits["decode"]
         self._jit_prefill = jits["prefill"]
         self._jit_decode_paged = jits["decode_paged"]
@@ -360,6 +385,14 @@ class InferenceEngine:
         return self.policy.admit_order(ready, now)
 
     # --------------------------------------------------------- telemetry
+    def _dispatching(self) -> None:
+        """Called just before each device dispatch: closes the host gap the
+        last token fetch opened, if one is open."""
+        if self._gap_from is not None:
+            self.stats.host_gap_s += _time.perf_counter() - self._gap_from
+            self.stats.host_gaps += 1
+            self._gap_from = None
+
     def _emit_span(self, kind: str, req: Request, tokens: int,
                    t0: float, t1: float) -> None:
         r = self._recorder
@@ -570,6 +603,7 @@ class InferenceEngine:
                         raise
                     self._evict(victim)
             if new != old:
+                self._dispatching()
                 self.cache = self._jit_copy_page(
                     self.cache, jnp.int32(old), jnp.int32(new))
                 self.stats.cow_forks += 1
@@ -649,39 +683,48 @@ class InferenceEngine:
         if len(piece) == 0:
             return True
         for lo in range(0, len(piece), self.prefill_chunk):
-            sub = piece[lo:lo + self.prefill_chunk]
-            c = len(sub)
-            tokens = np.zeros((self.max_slots, c), np.int32)
-            tokens[slot] = np.asarray(sub, np.int32)
-            mask = np.zeros((self.max_slots,), bool)
-            mask[slot] = True
-            if self.paged:
-                self.allocator.touch(slot)
-                self._cow_guard(slot, int(self.lengths[slot]), c)
-                _, self.cache = self._jit_prefill_paged(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.allocator.tables), jnp.asarray(mask),
-                    None)
-            else:
-                _, self.cache = self._jit_prefill(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(self.lengths), jnp.asarray(mask), None)
-            new_lengths = self.lengths.copy()
-            new_lengths[slot] += c
-            self.lengths = new_lengths
-            self.stats.prefill_tokens += c
-            self.stats.prefill_dispatches += 1
-            # cost + timestamp accrue per dispatched sub-chunk (identical
-            # totals for token-linear cost functions), so whole-prompt
-            # policies still expose intra-prompt boundaries to step-SLO
-            # accounting (Request.t_prefill)
-            t0 = self.now()
-            self._advance("prefill", c, req)
-            req.t_prefill.append(self.now())
-            self._emit_span("prefill", req, c, t0, self.now())
+            with TraceAnnotation("engine.prefill"):
+                c = self._prefill_dispatch(
+                    slot, piece[lo:lo + self.prefill_chunk])
+                # cost + timestamp accrue per dispatched sub-chunk
+                # (identical totals for token-linear cost functions), so
+                # whole-prompt policies still expose intra-prompt
+                # boundaries to step-SLO accounting (Request.t_prefill)
+                t0 = self.now()
+                self._advance("prefill", c, req)
+                req.t_prefill.append(self.now())
+                self._emit_span("prefill", req, c, t0, self.now())
         self._partial[slot] = upto
         return upto >= len(prompt)
+
+    def _prefill_dispatch(self, slot: int, sub: np.ndarray) -> int:
+        """One single-slot prefill dispatch of ``sub``; returns its width.
+        Every slot's row is computed, the mask gating the writes."""
+        c = len(sub)
+        tokens = np.zeros((self.max_slots, c), np.int32)
+        tokens[slot] = np.asarray(sub, np.int32)
+        mask = np.zeros((self.max_slots,), bool)
+        mask[slot] = True
+        if self.paged:
+            self.allocator.touch(slot)
+            self._cow_guard(slot, int(self.lengths[slot]), c)
+            self._dispatching()
+            _, self.cache = self._jit_prefill_paged(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(self.lengths),
+                jnp.asarray(self.allocator.tables), jnp.asarray(mask), None)
+        else:
+            self._dispatching()
+            _, self.cache = self._jit_prefill(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(self.lengths), jnp.asarray(mask), None)
+        new_lengths = self.lengths.copy()
+        new_lengths[slot] += c
+        self.lengths = new_lengths
+        self.stats.prefill_tokens += c
+        self.stats.prefill_dispatches += 1
+        self.stats.prefill_row_tokens += self.max_slots * c
+        return c
 
     def _prefill_budget_plan(self, prefilling: list[int],
                              budget: int) -> list[tuple[int, int]]:
@@ -738,6 +781,13 @@ class InferenceEngine:
             for slot, c in plan:
                 self._prefill_slot(slot, self.active[slot], c)
             return True
+        with TraceAnnotation("engine.prefill"):
+            self._prefill_rows(plan)
+        return True
+
+    def _prefill_rows(self, plan: list[tuple[int, int]]) -> None:
+        """One multi-slot prefill dispatch of ``plan``'s pieces at the
+        widest piece's width, then each row's cost, stamp and span."""
         width = max(c for _, c in plan)
         tokens = np.zeros((self.max_slots, width), np.int32)
         mask = np.zeros((self.max_slots,), bool)
@@ -755,6 +805,7 @@ class InferenceEngine:
         # the legacy single-slot path, one executable per (width, paged)
         val = (None if all(c == width for _, c in plan)
                else jnp.asarray(valid))
+        self._dispatching()
         if self.paged:
             _, self.cache = self._jit_prefill_paged(
                 self.params, self.cache, jnp.asarray(tokens),
@@ -765,6 +816,7 @@ class InferenceEngine:
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(self.lengths), jnp.asarray(mask), val)
         self.stats.prefill_dispatches += 1
+        self.stats.prefill_row_tokens += self.max_slots * width
         new_lengths = self.lengths.copy()
         for slot, c in plan:
             new_lengths[slot] += c
@@ -781,18 +833,21 @@ class InferenceEngine:
                     and self._recorder is not None):
                 self._recorder.instant("preempt", req.app, req.request_id,
                                        self.now())
-        return True
 
     # ------------------------------------------------------------- steps
     def step(self) -> list[tuple[int, int]]:
         """One engine step. Returns [(request_id, token)] emitted."""
         self.stats.steps += 1
-        emitted: list[tuple[int, int]] = []
+        with StepTraceAnnotation("engine.step", step_num=self.stats.steps):
+            with TraceAnnotation("engine.admit"):
+                self._admit()
+            return self._prefill_and_decode()
 
-        # 1) admit waiting requests into free slots (zeroed state). Paged
-        #    cache: admission is ALSO gated on free pages — each request
-        #    reserves pages for its actual prompt (not the max_seq worst
-        #    case), so small requests keep flowing while a big one waits.
+    def _admit(self) -> None:
+        """Admit waiting requests into free slots (zeroed state). Paged
+        cache: admission is ALSO gated on free pages — each request
+        reserves pages for its actual prompt (not the max_seq worst case),
+        so small requests keep flowing while a big one waits."""
         for req in self._admit_order():
             free = [i for i, a in enumerate(self.active) if a is None]
             if not free:
@@ -841,6 +896,7 @@ class InferenceEngine:
                 self.allocator.alloc_slot(slot, need_tok, shared=hit_pages)
                 self._note_pages()
                 self._emit_kv()
+            self._dispatching()
             self.cache = self._jit_set_slice(self.cache, slot,
                                              self._fresh_slot)
             new_lengths = self.lengths.copy()
@@ -860,8 +916,10 @@ class InferenceEngine:
                         "prefix_hit", req.app, req.request_id, t0,
                         tokens=hit, meta={"pages": len(hit_pages)})
 
-        # 2) prefill work — legacy one-slot-per-step, or budgeted multi-slot
-        #    when the policy's step_budget() hook splits the step's tokens
+    def _prefill_and_decode(self) -> list[tuple[int, int]]:
+        """The step's prefill work — legacy one-slot-per-step, or budgeted
+        multi-slot when the policy's step_budget() hook splits the step's
+        tokens — then one decode step for every fully-prefilled slot."""
         prefilling = [i for i, r in enumerate(self.active)
                       if r is not None and
                       self._partial.get(i, 0) < len(self._eff[i])]
@@ -895,7 +953,7 @@ class InferenceEngine:
             if prefilling and pf_budget > 0:
                 did_prefill = self._prefill_batch(prefilling, pf_budget)
 
-        # 3) decode step for all fully-prefilled slots
+        emitted: list[tuple[int, int]] = []
         decoded_n = 0
         if not skip_decode:
             emitted, decoded_n = self._decode_phase()
@@ -914,37 +972,13 @@ class InferenceEngine:
 
     def _decode_phase(self) -> tuple[list[tuple[int, int]], int]:
         """One batched decode dispatch over every fully-prefilled slot —
-        the active mask isolates mid-prefill/idle rows. Returns the
+        the active mask isolates mid-prefill/idle rows — then the argmax
+        fetch and the retirement of finished rows. Returns the
         ``(request_id, token)`` pairs emitted and how many rows decoded."""
-        emitted: list[tuple[int, int]] = []
-        decoding = [i for i, r in enumerate(self.active)
-                    if r is not None and
-                    self._partial.get(i, 0) >= len(self._eff[i])]
-        if self.paged and decoding:
-            # page growth before dispatch: the new token writes at position
-            # lengths[i]; growing may evict LRU victims (possibly other
-            # decoding slots — drop those from this step's batch)
-            for i in list(decoding):
-                if self.active[i] is None:
-                    continue   # evicted by an earlier slot's growth
-                if self._grow_pages(i, int(self.lengths[i]) + 1):
-                    # the new token writes into the page covering
-                    # lengths[i]; fork it first if it is shared (evictions
-                    # this triggers are re-filtered below, like growth's)
-                    self._cow_guard(i, int(self.lengths[i]), 1)
-                else:
-                    # pool smaller than this one row: finish cache-full
-                    req = self.active[i]
-                    req.t_done = self.now()
-                    self.done.append(req)
-                    self._publish_prefix(i)
-                    self.allocator.free_slot(i)
-                    self._emit_kv()
-                    self.active[i] = None
-                    self._partial.pop(i, None)
-                    self._eff.pop(i, None)
-            decoding = [i for i in decoding if self.active[i] is not None]
-        if decoding:
+        with TraceAnnotation("engine.decode"):
+            decoding = self._decode_rows()
+            if not decoding:
+                return [], 0
             mask = np.zeros((self.max_slots,), bool)
             tokens = np.zeros((self.max_slots, 1), np.int32)
             for i in decoding:
@@ -952,68 +986,116 @@ class InferenceEngine:
                 req = self.active[i]
                 tokens[i, 0] = (req.tokens_out[-1] if req.tokens_out
                                 else int(req.prompt[-1]))
+            t_step0 = self.now()
+            self.stats.kv_live_tokens += int(self.lengths.sum())
+            self.stats.kv_pool_tokens += self._pool_tokens
             if self.paged:
                 for i in decoding:
                     self.allocator.touch(i)
+                self._dispatching()
                 logits, self.cache = self._jit_decode_paged(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(self.lengths),
                     jnp.asarray(self.allocator.tables), jnp.asarray(mask))
             else:
+                self._dispatching()
                 logits, self.cache = self._jit_decode(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(self.lengths), jnp.asarray(mask))
-            t_step0 = self.now()
-            if self._req_cost is not None:
-                # shared hardware serializes service demand: the step costs
-                # the sum of every active row's per-token decode cost; each
-                # row's telemetry span covers its own serialized slice
-                for i in decoding:
-                    s0 = self.now()
-                    self._advance("decode", 1, self.active[i])
-                    self._emit_span("decode", self.active[i], 1, s0,
-                                    self.now())
-            else:
-                self._advance("decode", len(decoding))
-                if self._recorder is not None:
-                    # one batched dispatch: split the step interval across
-                    # rows so busy time is conserved (N overlapping spans
-                    # each claiming the full engine would overstate SMACT)
-                    dt = (self.now() - t_step0) / len(decoding)
-                    for j, i in enumerate(decoding):
-                        self._emit_span("decode", self.active[i], 1,
-                                        t_step0 + j * dt,
-                                        t_step0 + (j + 1) * dt)
-            t = self.now()
-            if self._last_decode_t is not None:
-                self.stats.max_decode_gap_s = max(
-                    self.stats.max_decode_gap_s, t - self._last_decode_t)
-            self._last_decode_t = t
+        with TraceAnnotation("engine.sample"):
             # the one host sync of the decode loop: fetch the argmaxes
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            self.stats.decode_syncs += 1
-            self.lengths = self.lengths + mask  # rebind, never mutate
-            for i in decoding:
-                req = self.active[i]
-                tok = int(nxt[i]) % self.cfg.vocab_size
-                req.tokens_out.append(tok)
-                req.t_tokens.append(t)
-                if req.t_first_token is None:
-                    req.t_first_token = t
-                emitted.append((req.request_id, tok))
-                full = int(self.lengths[i]) >= self.max_seq - 1
-                if len(req.tokens_out) >= req.max_new_tokens or full:
-                    req.t_done = t
-                    self.done.append(req)
-                    if self.paged:
-                        self._publish_prefix(i)
-                        self.allocator.free_slot(i)
-                        self._emit_kv()
-                    self.active[i] = None
-                    self._partial.pop(i, None)
-                    self._eff.pop(i, None)
-            self.stats.decode_tokens += len(decoding)
+        t_fetched = _time.perf_counter()
+        self.stats.decode_syncs += 1
+        with TraceAnnotation("engine.retire"):
+            emitted = self._retire(decoding, mask, nxt, t_step0)
+        if self.waiting or any(r is not None for r in self.active):
+            self._gap_from = t_fetched
         return emitted, len(decoding)
+
+    def _decode_rows(self) -> list[int]:
+        """The fully-prefilled slots to decode. Paged: page growth before
+        dispatch — the new token writes at position lengths[i]; growing may
+        evict LRU victims (possibly other decoding slots, dropped from this
+        step's batch), and a row the pool cannot grow finishes cache-full."""
+        decoding = [i for i, r in enumerate(self.active)
+                    if r is not None and
+                    self._partial.get(i, 0) >= len(self._eff[i])]
+        if not (self.paged and decoding):
+            return decoding
+        for i in list(decoding):
+            if self.active[i] is None:
+                continue   # evicted by an earlier slot's growth
+            if self._grow_pages(i, int(self.lengths[i]) + 1):
+                # the new token writes into the page covering lengths[i];
+                # fork it first if it is shared (evictions this triggers
+                # are re-filtered below, like growth's)
+                self._cow_guard(i, int(self.lengths[i]), 1)
+            else:
+                # pool smaller than this one row: finish cache-full
+                req = self.active[i]
+                req.t_done = self.now()
+                self.done.append(req)
+                self._publish_prefix(i)
+                self.allocator.free_slot(i)
+                self._emit_kv()
+                self.active[i] = None
+                self._partial.pop(i, None)
+                self._eff.pop(i, None)
+        return [i for i in decoding if self.active[i] is not None]
+
+    def _retire(self, decoding: list[int], mask: np.ndarray,
+                nxt: np.ndarray, t_step0: float) -> list[tuple[int, int]]:
+        """Cost, stamps and spans of a fetched decode step; appends each
+        row's token and finishes the rows that are done (freeing their
+        pages and publishing their prefixes)."""
+        emitted: list[tuple[int, int]] = []
+        if self._req_cost is not None:
+            # shared hardware serializes service demand: the step costs
+            # the sum of every active row's per-token decode cost; each
+            # row's telemetry span covers its own serialized slice
+            for i in decoding:
+                s0 = self.now()
+                self._advance("decode", 1, self.active[i])
+                self._emit_span("decode", self.active[i], 1, s0, self.now())
+        else:
+            self._advance("decode", len(decoding))
+            if self._recorder is not None:
+                # one batched dispatch: split the step interval across
+                # rows so busy time is conserved (N overlapping spans
+                # each claiming the full engine would overstate SMACT)
+                dt = (self.now() - t_step0) / len(decoding)
+                for j, i in enumerate(decoding):
+                    self._emit_span("decode", self.active[i], 1,
+                                    t_step0 + j * dt,
+                                    t_step0 + (j + 1) * dt)
+        t = self.now()
+        if self._last_decode_t is not None:
+            self.stats.max_decode_gap_s = max(
+                self.stats.max_decode_gap_s, t - self._last_decode_t)
+        self._last_decode_t = t
+        self.lengths = self.lengths + mask  # rebind, never mutate
+        for i in decoding:
+            req = self.active[i]
+            tok = int(nxt[i]) % self.cfg.vocab_size
+            req.tokens_out.append(tok)
+            req.t_tokens.append(t)
+            if req.t_first_token is None:
+                req.t_first_token = t
+            emitted.append((req.request_id, tok))
+            full = int(self.lengths[i]) >= self.max_seq - 1
+            if len(req.tokens_out) >= req.max_new_tokens or full:
+                req.t_done = t
+                self.done.append(req)
+                if self.paged:
+                    self._publish_prefix(i)
+                    self.allocator.free_slot(i)
+                    self._emit_kv()
+                self.active[i] = None
+                self._partial.pop(i, None)
+                self._eff.pop(i, None)
+        self.stats.decode_tokens += len(decoding)
+        return emitted
 
     def run(self, max_steps: int = 100_000) -> list[Request]:
         for _ in range(max_steps):

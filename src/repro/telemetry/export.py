@@ -65,10 +65,10 @@ def telemetry_block(sim: "SimResult", *, bins: int = TELEMETRY_BINS) -> dict:
         "spans": {app: [[_r(t0), _r(t1), kind] for t0, t1, kind in sp]
                   for app, sp in sorted(spans.items())},
     }
-    # Host CPU/RSS series are ALWAYS present: real runs with a
-    # HostMonitor wired to the recorder fill them, virtual-clock runs
-    # render zeros (counter_timeline zero-fills when no series match),
-    # keeping the block schema-identical across substrates.
+    # Host CPU/RSS series are ALWAYS present and zero-filled unless a
+    # recorder carries ``host_cpu_pct``/``host_rss_mb`` counters
+    # (counter_timeline zero-fills when no series match), keeping the
+    # block schema-identical across substrates.
     for name in ("host_cpu_pct", "host_rss_mb"):
         series = counter_timeline(trace, name, bins=bins, span_s=span)
         block[name] = [_r(v, 3) for v in series]

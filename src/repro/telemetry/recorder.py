@@ -53,9 +53,7 @@ prefix pages; ``tokens`` carries the prefill tokens skipped),
 ``ok``/``ttft_s``/``tpot_s``/``e2e_s``/``itl`` — so streaming consumers
 never need a second metrics path). Counters are named step series —
 both substrates emit ``kv_pages`` (suffix ``@<partition>`` on the
-engine) for the KV-pool occupancy timeline; real wall-clock runs add
-``host_cpu_pct`` / ``host_rss_mb`` via
-:class:`~repro.telemetry.host.HostMonitor`.
+engine) for the KV-pool occupancy timeline.
 
 Resilience events (repro.resilience): ``fault`` spans mark injected fault
 windows (app ``__faults__``, chips=0 — never chip-occupying work);

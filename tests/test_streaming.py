@@ -1,8 +1,8 @@
 """Streaming observability: quantile sketches vs exact percentiles, the
 online pipeline vs the post-hoc report, per-request critical-path
 assembly (completeness, partition invariant, cross-substrate parity),
-ring-buffer recorder bounds, the schema-1.8 attribution block, the ICI
-roofline term and the HostMonitor counter merge."""
+ring-buffer recorder bounds, the schema-1.8 attribution block and the ICI
+roofline term."""
 import json
 import math
 import random
@@ -13,9 +13,9 @@ from repro.bench import Scenario, ScenarioApp
 from repro.resilience.degradation import SloTracker
 from repro.roofline.analysis import achieved_fraction
 from repro.roofline.hw import TPU_V5E
-from repro.telemetry import (BUCKETS, HostMonitor, RequestAssembler,
+from repro.telemetry import (BUCKETS, RequestAssembler,
                              StreamingPipeline, TraceRecorder,
-                             attribution_from_trace, counter_timeline,
+                             attribution_from_trace,
                              empty_attribution_block)
 from repro.telemetry.streaming import GKSketch, P2Quantile, _interp_sorted
 
@@ -202,18 +202,7 @@ def test_achieved_fraction_ici_roof():
                              ici_bytes=10 * half_link) == 1.0
 
 
-# ------------------------------------------- satellites: host + burn rate
-def test_host_monitor_merges_counters_into_recorder():
-    tr = TraceRecorder()
-    mon = HostMonitor(recorder=tr)
-    mon._record({"t": 0.1, "cpu_pct": 50.0, "rss_mb": 100.0})
-    mon._record({"t": 0.2, "cpu_pct": 80.0, "rss_mb": 120.0})
-    assert tr.counters["host_cpu_pct"] == [(0.1, 50.0), (0.2, 80.0)]
-    assert tr.counters["host_rss_mb"] == [(0.1, 100.0), (0.2, 120.0)]
-    series = counter_timeline(tr, "host_cpu_pct", bins=2, span_s=0.2)
-    assert series[-1] == pytest.approx(80.0)
-
-
+# ------------------------------------------------------ satellite: burn rate
 def test_telemetry_block_host_series_zero_filled_without_monitor():
     blk = _concurrent("simulator").run().summary()["concurrent"]["telemetry"]
     assert all(v == 0.0 for v in blk["host_cpu_pct"])
