@@ -75,23 +75,26 @@ def attention_decode(q, k_cache, v_cache, lengths, rope_theta=None):
 
 
 def attention_decode_paged(q, k_pages, v_pages, block_tables, lengths,
-                           rope_theta=None):
-    """q: (B, 1, H, d); pools: (P, KV, page, d); block_tables: (B, nb);
-    lengths (B,) -> (B, 1, H, d).
+                           rope_theta=None, layer=0):
+    """q: (B, 1, H, d); pools: (L, P, KV, d, page) read at ``layer``, or one
+    layer's (P, KV, d, page) slab; block_tables: (B, nb); lengths (B,) ->
+    (B, 1, H, d).
 
     Paged counterpart of :func:`attention_decode`: K/V are gathered through
     the per-row block table instead of read from a contiguous per-slot
     cache. Same fused-RoPE contract."""
     be = backend()
     if be == "jnp":
-        from repro.models.attention import paged_decode_attention_jnp
-        return paged_decode_attention_jnp(q, k_pages, v_pages, block_tables,
-                                          lengths, rope_theta=rope_theta)
-    # the paged kernel consumes the model-layout pool directly — relayouting
-    # the whole pool per decode token would dwarf the attention itself
+        from repro.models.attention import (layer_pages,
+                                            paged_decode_attention_jnp)
+        return paged_decode_attention_jnp(
+            q, layer_pages(k_pages, layer), layer_pages(v_pages, layer),
+            block_tables, lengths, rope_theta=rope_theta)
+    # the paged kernel consumes the model-layout pool directly — slicing or
+    # relayouting the pool per decode token would dwarf the attention itself
     o = _pallas_paged_decode(q[:, 0], k_pages, v_pages,
                              jnp.asarray(block_tables, jnp.int32),
-                             jnp.asarray(lengths, jnp.int32),
+                             jnp.asarray(lengths, jnp.int32), layer,
                              rope_theta=rope_theta,
                              interpret=(be == "interpret"))
     return o[:, None]
@@ -123,28 +126,29 @@ def attention_prefill_chunk(q, k_cache, v_cache, start_len, rope_theta=None):
 
 
 def attention_prefill_chunk_paged(q, k_pages, v_pages, block_tables,
-                                  start_len, rope_theta=None):
-    """q: (B, C, H, d) UN-rotated; pools: (P, KV, page, d); block_tables:
-    (B, nb); start_len: (B,) -> (B, C, H, d).
+                                  start_len, rope_theta=None, layer=0):
+    """q: (B, C, H, d) UN-rotated; pools: (L, P, KV, d, page) read at
+    ``layer``, or one layer's (P, KV, d, page) slab; block_tables: (B, nb);
+    start_len: (B,) -> (B, C, H, d).
 
     Paged counterpart of :func:`attention_prefill_chunk`: K/V are gathered
     through the per-row block table (Pallas scalar-prefetch gather on TPU,
     materialized gather on jnp). Same fused-RoPE contract."""
     be = backend()
     if be == "jnp":
-        from repro.models.attention import (gather_pages,
+        from repro.models.attention import (gather_pages, layer_pages,
                                             prefill_chunk_attention_jnp)
-        k = gather_pages(k_pages, block_tables)
-        v = gather_pages(v_pages, block_tables)
+        k = gather_pages(layer_pages(k_pages, layer), block_tables)
+        v = gather_pages(layer_pages(v_pages, layer), block_tables)
         positions = jnp.asarray(start_len)[:, None] + \
             jnp.arange(q.shape[1])[None, :]
         return prefill_chunk_attention_jnp(q, k, v, positions,
                                            rope_theta=rope_theta)
-    # the paged kernel consumes the model-layout pool directly — relayouting
-    # the whole pool per prefill chunk would dwarf the attention itself
+    # the paged kernel consumes the model-layout pool directly — slicing or
+    # relayouting the pool per prefill chunk would dwarf the attention itself
     o = _pallas_paged_prefill(q.transpose(0, 2, 1, 3), k_pages, v_pages,
                               jnp.asarray(block_tables, jnp.int32),
-                              jnp.asarray(start_len, jnp.int32),
+                              jnp.asarray(start_len, jnp.int32), layer,
                               rope_theta=rope_theta,
                               interpret=(be == "interpret"))
     return o.transpose(0, 2, 1, 3)

@@ -1,7 +1,7 @@
 """Pallas TPU paged prefill-chunk flash attention: a chunk vs a PAGED cache.
 
 Same chunk-vs-cache online softmax as :mod:`repro.kernels.prefill_attention`
-with K/V living in the shared page pool ``(P, KV, page_size, d)`` instead of
+with K/V living in the shared page pool ``(P, KV, d, page_size)`` instead of
 a contiguous per-slot cache — the paged counterpart, exactly as
 :mod:`repro.kernels.paged_decode_attention` is to
 :mod:`repro.kernels.decode_attention`. The block table is a scalar-prefetch
@@ -14,8 +14,11 @@ sit beyond the row's causal horizon ``start_len + r//G`` and are masked by
 the online softmax. Rotary embedding of row r's query is fused at absolute
 position ``start_len + r//G`` (cached keys are rotated at write time).
 
-Layout: q (B, H, C, d) head-major; k/v pools (P, KV, page_size, d) — the
-MODEL layout, read in place; block_tables (B, nb) int32; start_len (B,).
+Layout: q (B, H, C, d) head-major; k/v pools (L, P, KV, d, page_size) —
+the MODEL layout of the whole stack (pages held transposed, as in
+:mod:`repro.kernels.paged_decode_attention`), read in place at a layer given
+as one more scalar-prefetch operand (a (P, KV, d, page_size) slab is a pool
+of one layer); block_tables (B, nb) int32; start_len (B,); layer () int32.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ from repro.kernels.prefill_attention import _rope_rotate_rows
 NEG_INF = -1e30
 
 
-def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float, page_size: int,
             num_blocks: int, c: int, g: int, rope_theta: float | None):
     b = pl.program_id(0)
@@ -54,9 +57,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         if rope_theta is not None:
             q = _rope_rotate_rows(q, qpos, rope_theta)
         q = q * scale
-        k = k_ref[0, 0].astype(jnp.float32)                  # (page, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (d, page)
+        s = jnp.dot(q, k, preferred_element_type=jnp.float32)
         pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos <= qpos, s, NEG_INF)               # per-row horizon
         m_prev = m_scr[...]
@@ -64,8 +66,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (page, d)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        v = v_ref[0, 0].astype(jnp.float32)                  # (d, page)
+        pv = jax.lax.dot_general(p, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
@@ -77,18 +79,22 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("rope_theta", "interpret"))
-def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
-                            rope_theta: float | None = None,
+def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len,
+                            layer=0, *, rope_theta: float | None = None,
                             interpret: bool = False):
-    """q: (B, H, C, d); k/v pools: (P, KV, page, d) read in place, the
-    chunk's keys/values already scattered into the rows' pages;
-    block_tables: (B, nb) int32 page ids; start_len: (B,) -> (B, H, C, d).
+    """q: (B, H, C, d); k/v pools: (L, P, KV, d, page) read in place at
+    layer ``layer``, the chunk's keys/values already written into the
+    rows' pages; a 4-D (P, KV, d, page) slab is a pool of one layer (layer
+    0); block_tables: (B, nb) int32 page ids; start_len: (B,) ->
+    (B, H, C, d).
 
     ``rope_theta``: fuse rotary embedding of chunk query j at absolute
     position ``start_len + j``.
     """
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, h, c, d = q.shape
-    kv, page = k_pages.shape[1], k_pages.shape[2]
+    kv, page = k_pages.shape[2], k_pages.shape[4]
     g = h // kv
     nb = block_tables.shape[1]
     scale = 1.0 / math.sqrt(d)
@@ -99,20 +105,22 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
                                num_blocks=nb, c=c, g=g,
                                rope_theta=rope_theta)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # block_tables, start_len
+        num_scalar_prefetch=3,           # block_tables, start_len, layer
         grid=(b, kv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, c * g, d),
-                         lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
+                         lambda b_, k_, j, bt, ln, ly: (b_, k_, 0, 0)),
             # the paged gather: grid step (b, k, j) streams the row's j-th
-            # page, resolved from the prefetched block table
-            pl.BlockSpec((1, 1, page, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda b_, k_, j, bt, ln: (bt[b_, j], k_, 0, 0)),
+            # page of the layer, resolved from the prefetched block table
+            pl.BlockSpec((None, 1, 1, d, page),
+                         lambda b_, k_, j, bt, ln, ly:
+                         (ly[0], bt[b_, j], k_, 0, 0)),
+            pl.BlockSpec((None, 1, 1, d, page),
+                         lambda b_, k_, j, bt, ln, ly:
+                         (ly[0], bt[b_, j], k_, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, c * g, d),
-                               lambda b_, k_, j, bt, ln: (b_, k_, 0, 0)),
+                               lambda b_, k_, j, bt, ln, ly: (b_, k_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((c * g, 1), jnp.float32),
             pltpu.VMEM((c * g, 1), jnp.float32),
@@ -125,6 +133,6 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, start_len, *,
         out_shape=jax.ShapeDtypeStruct((b, kv, c * g, d), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(start_len, jnp.int32),
-      qr, k_pages, v_pages)
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), qr, k_pages, v_pages)
     return (out.reshape(b, kv, c, g, d).transpose(0, 1, 3, 2, 4)
             .reshape(b, h, c, d))

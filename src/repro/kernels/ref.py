@@ -67,7 +67,7 @@ def paged_decode_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                rope_theta: float | None = None) -> Array:
     """Paged flash-decode oracle: gather pages, defer to the dense oracle.
 
-    q: (B, H, d); k/v pools: (P, KV, page, d) — the kernel's model layout;
+    q: (B, H, d); k/v pools: (P, KV, d, page) — the kernel's model layout;
     block_tables: (B, nb) int32 page ids; lengths: (B,). -> (B, H, d).
     Unallocated table entries hold a valid sentinel page; its stale
     contents sit past ``lengths`` and are masked, so the
@@ -79,10 +79,10 @@ def paged_decode_attention_ref(q: Array, k_pages: Array, v_pages: Array,
 
 
 def _pages_head_major(pool: Array, block_tables: Array) -> Array:
-    """pool (P, KV, page, d) gathered per row -> (B, KV, nb*page, d)."""
-    g = pool[block_tables]                          # (B, nb, KV, page, d)
-    b, nb, kv, page, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, kv, nb * page, d)
+    """pool (P, KV, d, page) gathered per row -> (B, KV, nb*page, d)."""
+    g = pool[block_tables]                          # (B, nb, KV, d, page)
+    b, nb, kv, d, page = g.shape
+    return g.transpose(0, 2, 1, 4, 3).reshape(b, kv, nb * page, d)
 
 
 def prefill_attention_ref(q: Array, k: Array, v: Array, start_len: Array,
@@ -119,7 +119,7 @@ def paged_prefill_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                 rope_theta: float | None = None) -> Array:
     """Paged prefill-chunk oracle: gather pages, defer to the dense oracle.
 
-    q: (B, H, C, d); k/v pools: (P, KV, page, d); block_tables: (B, nb)
+    q: (B, H, C, d); k/v pools: (P, KV, d, page); block_tables: (B, nb)
     int32 page ids; start_len: (B,). -> (B, H, C, d)."""
     return prefill_attention_ref(q, _pages_head_major(k_pages, block_tables),
                                  _pages_head_major(v_pages, block_tables),

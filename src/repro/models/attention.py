@@ -162,13 +162,20 @@ def prefill_chunk_attention_jnp(q: Array, k_full: Array, v_full: Array,
     return o.reshape(b, c, h, d)
 
 
+def layer_pages(pool: Array, layer) -> Array:
+    """One layer's (P, KV, d, page) slab of a whole stack's (L, P, KV, d,
+    page) pool; a 4-D pool is one layer's slab already."""
+    return pool if pool.ndim == 4 else pool[layer]
+
+
 def gather_pages(pool: Array, block_tables: Array) -> Array:
     """Contiguous per-row view of a paged pool (the jnp lowering's
-    materialized gather). pool: (P, KV, page, d) head-major model layout;
-    block_tables: (B, nb) int32 page ids -> (B, nb*page, KV, d)."""
-    g = pool[block_tables]                     # (B, nb, KV, page, d)
-    b, nb, kv, page, d = g.shape
-    return g.transpose(0, 1, 3, 2, 4).reshape(b, nb * page, kv, d)
+    materialized gather). pool: (P, KV, d, page) head-major model layout,
+    pages held transposed; block_tables: (B, nb) int32 page ids ->
+    (B, nb*page, KV, d)."""
+    g = pool[block_tables]                     # (B, nb, KV, d, page)
+    b, nb, kv, d, page = g.shape
+    return g.transpose(0, 1, 4, 2, 3).reshape(b, nb * page, kv, d)
 
 
 def paged_decode_attention_jnp(q: Array, k_pages: Array, v_pages: Array,
@@ -176,7 +183,7 @@ def paged_decode_attention_jnp(q: Array, k_pages: Array, v_pages: Array,
                                rope_theta: float | None = None) -> Array:
     """Single-token decode attention against a PAGED cache (jnp lowering).
 
-    q: (B, 1, H, d); pools: (P, KV, page, d) model layout; block_tables:
+    q: (B, 1, H, d); pools: (P, KV, d, page) model layout; block_tables:
     (B, nb) int32 page ids; length: (B,) valid prefix per row.
 
     The jnp fallback materializes the gathered view (:func:`gather_pages`)

@@ -155,8 +155,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     ld = cfg.num_decoder_layers
     tf = frames_len(max_seq)
     return {
-        "k_pages": jnp.zeros((ld, num_pages, kv, page_size, hd), dtype),
-        "v_pages": jnp.zeros((ld, num_pages, kv, page_size, hd), dtype),
+        "k_pages": jnp.zeros((ld, num_pages, kv, hd, page_size), dtype),
+        "v_pages": jnp.zeros((ld, num_pages, kv, hd, page_size), dtype),
         "cross_k": jnp.zeros((ld, batch, tf, kv, hd), dtype),
         "cross_v": jnp.zeros((ld, batch, tf, kv, hd), dtype),
     }

@@ -146,8 +146,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     states = jax.tree.map(
         lambda a: jnp.zeros((nb, n_ssm) + a.shape, a.dtype), one)
     return {
-        "k_pages": jnp.zeros((nb, num_pages, kvh, page_size, hd), dtype),
-        "v_pages": jnp.zeros((nb, num_pages, kvh, page_size, hd), dtype),
+        "k_pages": jnp.zeros((nb, num_pages, kvh, hd, page_size), dtype),
+        "v_pages": jnp.zeros((nb, num_pages, kvh, hd, page_size), dtype),
         "ssm": states,
     }
 

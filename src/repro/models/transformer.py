@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers, moe
 from repro.models.attention import (decode_attention_jnp, flash_attention_jnp,
-                                    gather_pages, naive_attention,
+                                    gather_pages, layer_pages,
+                                    naive_attention,
                                     prefill_chunk_attention_jnp)
 
 Array = jax.Array
@@ -197,40 +198,104 @@ def attention_decode_block(p: dict, x: Array, cfg: ModelConfig,
 def attention_decode_block_paged(p: dict, x: Array, cfg: ModelConfig,
                                  k_pages: Array, v_pages: Array,
                                  block_tables: Array, lengths: Array,
-                                 active: Array | None = None):
+                                 active: Array | None = None,
+                                 layer: Array | int = 0):
     """One-token attention against a PAGED cache.
 
-    x: (B,1,D); pools: (P, KV, page, hd) shared across rows; block_tables:
-    (B, nb) int32 page ids. The new k/v lands in the page covering position
-    ``lengths`` (the engine maps that page before dispatch); attention
-    gathers K/V through the block table (``ops.attention_decode_paged`` —
-    Pallas scalar-prefetch gather on TPU, materialized gather on jnp).
+    x: (B,1,D); pools: the whole stack's (L, P, KV, hd, page), read and
+    written at ``layer``, or one layer's (P, KV, hd, page) slab (the pool's
+    rank says which), shared across rows; block_tables: (B, nb) int32 page
+    ids. The new k/v lands in the page covering position ``lengths`` (the
+    engine maps that page before dispatch); attention gathers K/V through
+    the block table (``ops.attention_decode_paged`` — Pallas scalar-prefetch
+    gather on TPU, materialized gather on jnp). A layer scan that carries
+    the whole pool updates it in place: one (hd,) column per row and
+    layer is written, and no slab is sliced or copied.
 
-    ``active``: inactive rows write nothing — their target page id is
-    pushed past the pool end so the ``mode="drop"`` scatter discards it.
-    Same contract as :func:`attention_decode_block`; no int8 path (the
-    engine falls back to the contiguous cache under ``kv_cache_dtype``
-    hints).
+    ``active``: inactive rows write nothing. Same contract as
+    :func:`attention_decode_block`; no int8 path (the engine falls back to
+    the contiguous cache under ``kv_cache_dtype`` hints).
     """
     positions = lengths[:, None]
     q, k, v = _project_qkv(p, x, cfg, positions, rope_q=False)
 
-    num_pages, page = k_pages.shape[0], k_pages.shape[2]
+    page = k_pages.shape[-1]
     block = jnp.minimum(lengths // page, block_tables.shape[1] - 1)
     pidx = jnp.take_along_axis(block_tables, block[:, None], axis=1)[:, 0]
-    off = lengths % page
-    if active is not None:
-        pidx = jnp.where(active, pidx, jnp.int32(num_pages))  # drop writes
-    # (B,) page ids and offsets around the head axis index (B, KV, hd)
-    k_pages = k_pages.at[pidx, :, off].set(
-        k[:, 0].astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[pidx, :, off].set(
-        v[:, 0].astype(v_pages.dtype), mode="drop")
+    write = jnp.ones(lengths.shape, bool) if active is None else active
+    k_pages = _write_tokens(k_pages, layer, pidx, lengths % page, k[:, 0],
+                            write)
+    v_pages = _write_tokens(v_pages, layer, pidx, lengths % page, v[:, 0],
+                            write)
     from repro.kernels import ops
     o = ops.attention_decode_paged(q, k_pages, v_pages, block_tables,
-                                   lengths + 1, rope_theta=cfg.rope_theta)
+                                   lengths + 1, rope_theta=cfg.rope_theta,
+                                   layer=layer)
     out = jnp.einsum("bshe,hed->bsd", o, p["wo"])
     return out, (k_pages, v_pages)
+
+
+def _write_tokens(pages: Array, layer, pidx: Array, off: Array,
+                  new: Array, write: Array) -> Array:
+    """Write one token per row: ``new[b]`` (KV, hd) as the column at
+    offset ``off[b]`` of page ``pidx[b]``, where ``write[b]``; other rows
+    write their column back unchanged. A token is one column of a
+    transposed (hd, page) page, so each row is a dynamic-update-slice of
+    that column in place: a scatter of the columns would lay the pool out
+    with hd innermost, and XLA would copy the pool there and back at every
+    layer. ``pages``: (L, P, KV, hd, page) at ``layer``, or one layer's
+    (P, KV, hd, page)."""
+    pool = pages if pages.ndim == 5 else pages[None]
+    lay = layer if pages.ndim == 5 else 0
+    size = (1, 1) + pool.shape[2:4] + (1,)
+    for b in range(new.shape[0]):
+        at = (lay, pidx[b], 0, 0, off[b])
+        old = jax.lax.dynamic_slice(pool, at, size)
+        col = new[b].astype(pool.dtype)[None, None, :, :, None]
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(write[b], col, old), at)
+    return pool if pages.ndim == 5 else pool[0]
+
+
+def _write_chunk(pages: Array, layer, block_tables: Array, start: Array,
+                 new: Array, write: Array) -> Array:
+    """Write each row's chunk: ``new[b, c]`` (KV, hd) at position
+    ``start[b] + c``, where ``write[b, c]``. A chunk's columns can straddle
+    two pages, so no single column block fits: each page a writing row's
+    chunk can touch is read, merged on the token axis and written back
+    whole (see :func:`_write_tokens` for why not a scatter). One loop step
+    per row and page, over the rows that write anything: a prefill
+    dispatch of one live row among many touches one row's pages, and the
+    program stays one loop body long whatever the batch. ``pages`` as in
+    :func:`_write_tokens`."""
+    pool = pages if pages.ndim == 5 else pages[None]
+    lay = layer if pages.ndim == 5 else 0
+    c = write.shape[1]
+    kv, hd, page = pool.shape[2:]
+    nb = block_tables.shape[1]
+    span = (c + page - 2) // page + 1            # pages a chunk can touch
+    writes = write.any(axis=1)
+    rows = jnp.argsort(~writes)                  # writing rows first
+    # token c of row b at column page + c: a page's worth of slack each
+    # side, so the columns of every page a chunk touches slice out whole
+    pad = ((0, 0), (page, page))
+    cols = jnp.pad(new.astype(pool.dtype).transpose(0, 2, 3, 1),
+                   ((0, 0), (0, 0)) + pad)                # (B, KV, hd, ...)
+    mask = jnp.pad(write, pad)
+
+    def one(i, pool):
+        r, t = rows[i // span], i % span
+        blk = start[r] // page + t
+        at = (lay, block_tables[r, jnp.minimum(blk, nb - 1)], 0, 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, kv, hd, page))
+        lo = blk * page - start[r] + page
+        tok = jax.lax.dynamic_slice(cols, (r, 0, 0, lo), (1, kv, hd, page))
+        hit = jax.lax.dynamic_slice(mask, (r, lo), (1, page))
+        merged = jnp.where(hit[:, None, None, :], tok, old[0])
+        return jax.lax.dynamic_update_slice(pool, merged[None], at)
+
+    pool = jax.lax.fori_loop(0, writes.sum() * span, one, pool)
+    return pool if pages.ndim == 5 else pool[0]
 
 
 def _chunk_attend(p: dict, q: Array, k_full: Array, v_full: Array,
@@ -255,42 +320,39 @@ def attention_prefill_chunk_block_paged(p: dict, x: Array, cfg: ModelConfig,
                                         k_pages: Array, v_pages: Array,
                                         block_tables: Array, start_len: Array,
                                         active: Array | None = None,
-                                        valid: Array | None = None):
+                                        valid: Array | None = None,
+                                        layer: Array | int = 0):
     """Chunked-prefill attention against a PAGED cache: C new tokens are
     scattered into their rows' pages (positions ``start_len ..
     start_len+C-1`` resolved through the block table) and attended causally
     over the gathered padded view. Same semantics as
     :func:`attention_prefill_chunk_block` with the cache paged (pad-token
-    page ids pushed past the pool end under ``valid``)."""
+    tokens write nothing under ``valid``). The pools are the whole stack's
+    at ``layer`` or one layer's slab, as in
+    :func:`attention_decode_block_paged`."""
     b, c, _ = x.shape
-    num_pages, page = k_pages.shape[0], k_pages.shape[2]
-    nb = block_tables.shape[1]
     positions = start_len[:, None] + jnp.arange(c)[None, :]       # (B,C)
     q, k, v = _project_qkv(p, x, cfg, positions, rope_q=False)
 
-    block = jnp.minimum(positions // page, nb - 1)                # (B,C)
-    pidx = jnp.take_along_axis(block_tables, block, axis=1)       # (B,C)
-    off = positions % page
+    write = jnp.ones((b, c), bool)
     if active is not None:
-        pidx = jnp.where(active[:, None], pidx, jnp.int32(num_pages))
+        write = write & active[:, None]
     if valid is not None:
-        tok_ok = jnp.arange(c)[None, :] < valid[:, None]          # (B,C)
-        pidx = jnp.where(tok_ok, pidx, jnp.int32(num_pages))
-    k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype),
-                                           mode="drop")
-    v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype),
-                                           mode="drop")
+        write = write & (jnp.arange(c)[None, :] < valid[:, None])
+    k_pages = _write_chunk(k_pages, layer, block_tables, start_len, k, write)
+    v_pages = _write_chunk(v_pages, layer, block_tables, start_len, v, write)
 
     from repro.kernels import ops
     if ops.backend() != "jnp":
         # stream pages through the block table in-kernel — never gather
         o = ops.attention_prefill_chunk_paged(q, k_pages, v_pages,
                                               block_tables, start_len,
-                                              rope_theta=cfg.rope_theta)
+                                              rope_theta=cfg.rope_theta,
+                                              layer=layer)
         out = jnp.einsum("bshe,hed->bsd", o.astype(x.dtype), p["wo"])
         return out, (k_pages, v_pages)
-    k_full = gather_pages(k_pages, block_tables)
-    v_full = gather_pages(v_pages, block_tables)
+    k_full = gather_pages(layer_pages(k_pages, layer), block_tables)
+    v_full = gather_pages(layer_pages(v_pages, layer), block_tables)
     out = _chunk_attend(p, q, k_full, v_full, positions, cfg, x.dtype)
     return out, (k_pages, v_pages)
 
@@ -362,6 +424,14 @@ def _ffn(p: dict, x: Array, cfg: ModelConfig,
     return layers.mlp(p, x), jnp.zeros((), jnp.float32)
 
 
+def _logits(params: dict, x: Array, cfg: ModelConfig) -> Array:
+    """Final norm and unembedding."""
+    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    if cfg.tie_embeddings:
+        return layers.unembed(x, params["embedding"], transpose=True)
+    return layers.unembed(x, params["lm_head"], transpose=False)
+
+
 # ---------------------------------------------------------------- forward
 
 def _remat(fn, policy: str):
@@ -412,11 +482,7 @@ def forward(params: dict, tokens: Array, cfg: ModelConfig, *,
     body = _remat(body, remat)
     (x, aux), kv = layers.scan(body, (x, jnp.zeros((), jnp.float32)),
                                 params["layers"])
-    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(x, params["embedding"], transpose=True)
-    else:
-        logits = layers.unembed(x, params["lm_head"], transpose=False)
+    logits = _logits(params, x, cfg)
     if return_cache:
         cache = {"k": kv[0], "v": kv[1]}
         return logits, aux, cache
@@ -443,15 +509,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16) -> dict:
     """Page-pool KV cache: ``num_pages`` shared pages of ``page_size``
-    tokens per layer, head-major ``(L, P, KV, page, hd)`` so a page's
-    per-head ``(page, hd)`` slab is one tile of the paged kernels; rows
-    address pages through engine-side block tables. No int8 variant — the
-    engine keeps the contiguous cache under ``kv_cache_dtype`` hints."""
+    tokens per layer, head-major and transposed, ``(L, P, KV, hd, page)``,
+    so a page's per-head ``(hd, page)`` slab is one tile of the paged
+    kernels, held unpadded in the device's default layout (see
+    :mod:`repro.kernels.paged_decode_attention`); rows address pages
+    through engine-side block tables. No int8 variant — the engine keeps
+    the contiguous cache under ``kv_cache_dtype`` hints."""
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     l = cfg.num_layers
     return {
-        "k_pages": jnp.zeros((l, num_pages, kv, page_size, hd), dtype),
-        "v_pages": jnp.zeros((l, num_pages, kv, page_size, hd), dtype),
+        "k_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
+        "v_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
     }
 
 
@@ -507,11 +575,7 @@ def decode_step(params: dict, cache: dict, tokens: Array, lengths: Array,
         x, (k_new, v_new) = layers.scan(
             body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = {"k": k_new, "v": v_new}
-    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(x, params["embedding"], transpose=True)
-    else:
-        logits = layers.unembed(x, params["lm_head"], transpose=False)
+    logits = _logits(params, x, cfg)
     return logits[:, 0], new_cache
 
 
@@ -521,28 +585,29 @@ def decode_step_paged(params: dict, cache: dict, tokens: Array,
     """One decode step against the paged cache. tokens: (B,1); lengths:
     (B,); block_tables: (B, nb). Same contract as :func:`decode_step`
     (logits (B,V), new cache; inactive rows untouched), with K/V written
-    into and gathered from the shared page pool."""
+    into and gathered from the shared page pool. The layer scan carries the
+    whole pool and each layer updates it in place at its own index, so a
+    step moves no pool-sized slab (with the pool donated, not one copy)."""
     x = layers.embed(params["embedding"], tokens)
 
-    def body(x, inp):
-        lp, kp, vp = inp
+    def body(carry, inp):
+        x, kp, vp = carry
+        lp, l = inp
         h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, caches = attention_decode_block_paged(
-            lp["attn"], h, cfg, kp, vp, block_tables, lengths, active=active)
+        attn_out, (kp, vp) = attention_decode_block_paged(
+            lp["attn"], h, cfg, kp, vp, block_tables, lengths, active=active,
+            layer=l)
         x = x + attn_out
         h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
         ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
         x = x + ffn_out
-        return x, caches
+        return (x, kp, vp), None
 
-    x, (k_new, v_new) = layers.scan(
-        body, x, (params["layers"], cache["k_pages"], cache["v_pages"]))
-    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(x, params["embedding"], transpose=True)
-    else:
-        logits = layers.unembed(x, params["lm_head"], transpose=False)
-    return logits[:, 0], {"k_pages": k_new, "v_pages": v_new}
+    pool = (cache["k_pages"], cache["v_pages"])
+    (x, k_new, v_new), _ = layers.scan(
+        body, (x,) + pool,
+        (params["layers"], jnp.arange(pool[0].shape[0])))
+    return _logits(params, x, cfg)[:, 0], {"k_pages": k_new, "v_pages": v_new}
 
 
 def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
@@ -550,29 +615,28 @@ def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
                         cfg: ModelConfig, active: Array | None = None,
                         valid: Array | None = None):
     """Batched chunked prefill against the paged cache; see
-    :func:`prefill_chunk` for the contract."""
+    :func:`prefill_chunk` for the contract. The pool rides the layer scan's
+    carry and is updated in place, as in :func:`decode_step_paged`."""
     x = layers.embed(params["embedding"], tokens)
 
-    def body(x, inp):
-        lp, kp, vp = inp
+    def body(carry, inp):
+        x, kp, vp = carry
+        lp, l = inp
         h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, caches = attention_prefill_chunk_block_paged(
+        attn_out, (kp, vp) = attention_prefill_chunk_block_paged(
             lp["attn"], h, cfg, kp, vp, block_tables, start_len,
-            active=active, valid=valid)
+            active=active, valid=valid, layer=l)
         x = x + attn_out
         h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
         ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
         x = x + ffn_out
-        return x, caches
+        return (x, kp, vp), None
 
-    x, (k_new, v_new) = layers.scan(
-        body, x, (params["layers"], cache["k_pages"], cache["v_pages"]))
-    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(x, params["embedding"], transpose=True)
-    else:
-        logits = layers.unembed(x, params["lm_head"], transpose=False)
-    return logits, {"k_pages": k_new, "v_pages": v_new}
+    pool = (cache["k_pages"], cache["v_pages"])
+    (x, k_new, v_new), _ = layers.scan(
+        body, (x,) + pool,
+        (params["layers"], jnp.arange(pool[0].shape[0])))
+    return _logits(params, x, cfg), {"k_pages": k_new, "v_pages": v_new}
 
 
 def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
@@ -620,9 +684,5 @@ def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
         x, (k_new, v_new) = layers.scan(
             body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = {"k": k_new, "v": v_new}
-    x = layers.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(x, params["embedding"], transpose=True)
-    else:
-        logits = layers.unembed(x, params["lm_head"], transpose=False)
+    logits = _logits(params, x, cfg)
     return logits, new_cache

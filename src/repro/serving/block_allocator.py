@@ -8,7 +8,7 @@ every Scenario. The paged refactor replaces that reservation with a pool of
 fixed-size pages plus one block table per decode slot:
 
 * **pool** — ``num_pages`` pages of ``page_size`` tokens each. Model-side
-  the pool is a per-layer array ``(P, KV, page_size, hd)``; a page id
+  the pool is a per-layer array ``(P, KV, hd, page_size)``; a page id
   indexes the same row of every layer's pool (vLLM-style layout).
 * **block table** — ``(max_slots, max_blocks)`` int32 page ids. Unassigned
   entries hold ``SENTINEL`` (page 0): always safe to *gather* (the data is

@@ -313,19 +313,26 @@ class InferenceEngine:
         # each named after the model method it runs so a profiler trace
         # tells them apart; shared across engines of the same ModelBundle
         # so multiple engines (or an engine plus its serve-alone test
-        # oracle) reuse executables
+        # oracle) reuse executables. Every program that takes the cache
+        # donates it: the cache it returns reuses the old one's buffers, so
+        # a step writes only what it changes and one cache is held at a
+        # time. The engine rebinds ``self.cache`` at every call and never
+        # reads a donated cache again; nothing else is donated.
         jits = getattr(model, "_serving_jit_cache", None)
         if jits is None:
             jits = model._serving_jit_cache = {
-                "decode": jax.jit(model.decode_step),
-                "prefill": jax.jit(model.prefill_chunk),
-                "decode_paged": jax.jit(model.decode_step_paged),
-                "prefill_paged": jax.jit(model.prefill_chunk_paged),
+                "decode": jax.jit(model.decode_step, donate_argnums=(1,)),
+                "prefill": jax.jit(model.prefill_chunk, donate_argnums=(1,)),
+                "decode_paged": jax.jit(model.decode_step_paged,
+                                        donate_argnums=(1,)),
+                "prefill_paged": jax.jit(model.prefill_chunk_paged,
+                                         donate_argnums=(1,)),
                 "set_slice": jax.jit(model.set_cache_slice,
-                                     static_argnums=(1,)),
+                                     static_argnums=(1,),
+                                     donate_argnums=(0,)),
                 # CoW fork: page ids stay traced — ONE executable serves
                 # every fork of this model's pool
-                "copy_page": jax.jit(model.copy_page),
+                "copy_page": jax.jit(model.copy_page, donate_argnums=(0,)),
             }
         self._jit_decode = jits["decode"]
         self._jit_prefill = jits["prefill"]

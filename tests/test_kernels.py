@@ -307,17 +307,28 @@ def test_prefill_attention_fused_rope(rng_key):
     (1, 4, 1, 8, 32, 8, 16, 32),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layer", [None, 1])
 def test_paged_prefill_attention(b, h, kv, c, d, page, nb, pool, dtype,
-                                 rng_key):
+                                 layer, rng_key):
+    """``layer=None``: one layer's 4-D (P, KV, d, page) slab; otherwise a
+    5-D pool of two layers read at ``layer``, against the oracle on that
+    layer's slab."""
     ks = jax.random.split(rng_key, 5)
     q = jax.random.normal(ks[0], (b, h, c, d), dtype)
-    k_pages = jax.random.normal(ks[1], (pool, kv, page, d), dtype)
-    v_pages = jax.random.normal(ks[2], (pool, kv, page, d), dtype)
+    lead = () if layer is None else (2,)
+    k_pool = jax.random.normal(ks[1], lead + (pool, kv, d, page), dtype)
+    v_pool = jax.random.normal(ks[2], lead + (pool, kv, d, page), dtype)
     tables = jax.random.randint(ks[3], (b, nb), 0, pool).astype(jnp.int32)
     s = nb * page
     start = jax.random.randint(ks[4], (b,), 0, s - c + 1).astype(jnp.int32)
-    out = paged_prefill_attention(q, k_pages, v_pages, tables, start,
-                                  interpret=True)
+    if layer is None:
+        out = paged_prefill_attention(q, k_pool, v_pool, tables, start,
+                                      interpret=True)
+        k_pages, v_pages = k_pool, v_pool
+    else:
+        out = paged_prefill_attention(q, k_pool, v_pool, tables, start,
+                                      jnp.int32(layer), interpret=True)
+        k_pages, v_pages = k_pool[layer], v_pool[layer]
     want = ref.paged_prefill_attention_ref(q, k_pages, v_pages, tables,
                                            start)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -332,8 +343,8 @@ def test_paged_prefill_attention_fused_rope(rng_key):
     theta = 10_000.0
     ks = jax.random.split(rng_key, 5)
     q = jax.random.normal(ks[0], (b, h, c, d))
-    k_pages = jax.random.normal(ks[1], (pool, kv, page, d))
-    v_pages = jax.random.normal(ks[2], (pool, kv, page, d))
+    k_pages = jax.random.normal(ks[1], (pool, kv, d, page))
+    v_pages = jax.random.normal(ks[2], (pool, kv, d, page))
     tables = jax.random.randint(ks[3], (b, nb), 0, pool).astype(jnp.int32)
     s = nb * page
     start = jax.random.randint(ks[4], (b,), 0, s - c + 1).astype(jnp.int32)
@@ -344,8 +355,8 @@ def test_paged_prefill_attention_fused_rope(rng_key):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     # dense-oracle cross-check on the gathered view
-    kd = k_pages[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, s, d)
-    vd = v_pages[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, s, d)
+    kd = k_pages[tables].transpose(0, 2, 1, 4, 3).reshape(b, kv, s, d)
+    vd = v_pages[tables].transpose(0, 2, 1, 4, 3).reshape(b, kv, s, d)
     dense = ref.prefill_attention_ref(q, kd, vd, start, rope_theta=theta)
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)

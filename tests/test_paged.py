@@ -1,6 +1,7 @@
 """Paged KV cache: kernel vs oracle, per-family parity with the contiguous
 path, and engine-level admission/eviction semantics."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,20 +24,32 @@ from repro.serving.request import Request, chat_trace
     (1, 4, 1, 32, 16, 3),      # MQA, small pages
 ])
 @pytest.mark.parametrize("rope_theta", [None, 1e4])
+@pytest.mark.parametrize("layer", [None, 2])
 def test_paged_kernel_matches_oracle(b, h, kv, d, page, nb, rope_theta,
-                                     rng_key):
+                                     layer, rng_key):
+    """``layer=None``: one layer's 4-D (P, KV, d, page) slab; otherwise a
+    5-D pool of three layers read at ``layer``, against the oracle on that
+    layer's slab."""
     num_pages = nb * b + 2
     ks = jax.random.split(rng_key, 4)
     q = jax.random.normal(ks[0], (b, h, d))
-    k_pages = jax.random.normal(ks[1], (num_pages, kv, page, d))
-    v_pages = jax.random.normal(ks[2], (num_pages, kv, page, d))
+    lead = () if layer is None else (3,)
+    k_pool = jax.random.normal(ks[1], lead + (num_pages, kv, d, page))
+    v_pool = jax.random.normal(ks[2], lead + (num_pages, kv, d, page))
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(num_pages)[:b * nb].reshape(b, nb),
                      jnp.int32)
     lengths = jax.random.randint(ks[3], (b,), 1, nb * page + 1)
     lengths = lengths.astype(jnp.int32)
-    out = paged_decode_attention(q, k_pages, v_pages, bt, lengths,
-                                 rope_theta=rope_theta, interpret=True)
+    if layer is None:
+        out = paged_decode_attention(q, k_pool, v_pool, bt, lengths,
+                                     rope_theta=rope_theta, interpret=True)
+        k_pages, v_pages = k_pool, v_pool
+    else:
+        out = paged_decode_attention(q, k_pool, v_pool, bt, lengths,
+                                     jnp.int32(layer), rope_theta=rope_theta,
+                                     interpret=True)
+        k_pages, v_pages = k_pool[layer], v_pool[layer]
     want = ref.paged_decode_attention_ref(q, k_pages, v_pages, bt, lengths,
                                           rope_theta=rope_theta)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -49,14 +62,14 @@ def test_paged_kernel_ignores_unowned_pages(rng_key):
     b, h, kv, d, page, nb = 1, 4, 2, 32, 16, 4
     ks = jax.random.split(rng_key, 3)
     q = jax.random.normal(ks[0], (b, h, d))
-    k_pages = jax.random.normal(ks[1], (8, kv, page, d))
-    v_pages = jax.random.normal(ks[2], (8, kv, page, d))
+    k_pages = jax.random.normal(ks[1], (8, kv, d, page))
+    v_pages = jax.random.normal(ks[2], (8, kv, d, page))
     bt = jnp.asarray([[3, 5, 0, 0]], jnp.int32)   # tail entries = sentinel
     lengths = jnp.asarray([20], jnp.int32)        # only pages 3,5 valid
     out1 = paged_decode_attention(q, k_pages, v_pages, bt, lengths,
                                   interpret=True)
-    k2 = k_pages.at[0].set(999.0).at[5, :, 4:].set(-999.0)
-    v2 = v_pages.at[0].set(-999.0).at[5, :, 4:].set(999.0)
+    k2 = k_pages.at[0].set(999.0).at[5, :, :, 4:].set(-999.0)
+    v2 = v_pages.at[0].set(-999.0).at[5, :, :, 4:].set(999.0)
     out2 = paged_decode_attention(q, k2, v2, bt, lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
 
@@ -70,8 +83,8 @@ def test_paged_jnp_fallback_matches_contiguous(rng_key):
     k = jax.random.normal(ks[1], (b, s, kv, d))
     v = jax.random.normal(ks[2], (b, s, kv, d))
     nb = s // page
-    k_pages = k.reshape(b * nb, page, kv, d).transpose(0, 2, 1, 3)
-    v_pages = v.reshape(b * nb, page, kv, d).transpose(0, 2, 1, 3)
+    k_pages = k.reshape(b * nb, page, kv, d).transpose(0, 2, 3, 1)
+    v_pages = v.reshape(b * nb, page, kv, d).transpose(0, 2, 3, 1)
     bt = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
     lengths = jnp.asarray([37, 64], jnp.int32)
     got = paged_decode_attention_jnp(q, k_pages, v_pages, bt, lengths,
@@ -95,14 +108,24 @@ def _family_model(arch, rng_key):
     return m, m.init(rng_key), cfg
 
 
+@pytest.mark.parametrize("programs", ["eager", "donated"])
 @pytest.mark.parametrize("arch", PAGED_ARCHS)
-def test_paged_decode_token_identical_per_family(arch, rng_key):
+def test_paged_decode_token_identical_per_family(arch, programs, rng_key):
     """The tentpole parity pin: chunked prefill + greedy decode through the
     PAGED cache produces the same logits (tight tolerance) and the same
-    argmax tokens as the contiguous cache, for every family with KV."""
+    argmax tokens as the contiguous cache, for every family with KV.
+    ``donated``: through the engine's jitted programs, each of which
+    donates the cache it is handed."""
     m, params, cfg = _family_model(arch, rng_key)
     assert m.cache_pages()
     b, plen, max_seq, page = 2, 13, 32, 8
+    if programs == "donated":
+        eng = InferenceEngine(m, max_slots=b, max_seq=max_seq, page_size=page)
+        prefill_c, prefill_p = eng._jit_prefill, eng._jit_prefill_paged
+        decode_c, decode_p = eng._jit_decode, eng._jit_decode_paged
+    else:
+        prefill_c, prefill_p = m.prefill_chunk, m.prefill_chunk_paged
+        decode_c, decode_p = m.decode_step, m.decode_step_paged
     toks = jax.random.randint(rng_key, (b, plen), 0, cfg.vocab_size)
     cache_c = m.init_cache(b, max_seq)
     cache_p = m.init_paged_cache(8, page, b, max_seq)
@@ -110,9 +133,8 @@ def test_paged_decode_token_identical_per_family(arch, rng_key):
     start = jnp.zeros((b,), jnp.int32)
     for lo in range(0, plen, 5):         # chunk 5: non-divisible tail
         hi = min(plen, lo + 5)
-        lc, cache_c = m.prefill_chunk(params, cache_c, toks[:, lo:hi], start)
-        lp, cache_p = m.prefill_chunk_paged(params, cache_p, toks[:, lo:hi],
-                                            start, bt)
+        lc, cache_c = prefill_c(params, cache_c, toks[:, lo:hi], start)
+        lp, cache_p = prefill_p(params, cache_p, toks[:, lo:hi], start, bt)
         start = start + (hi - lo)
     np.testing.assert_allclose(np.asarray(lp, np.float32),
                                np.asarray(lc, np.float32),
@@ -120,8 +142,8 @@ def test_paged_decode_token_identical_per_family(arch, rng_key):
     ln = jnp.full((b,), plen, jnp.int32)
     tok = toks[:, -1:]
     for _ in range(4):
-        dc, cache_c = m.decode_step(params, cache_c, tok, ln)
-        dp, cache_p = m.decode_step_paged(params, cache_p, tok, ln, bt)
+        dc, cache_c = decode_c(params, cache_c, tok, ln)
+        dp, cache_p = decode_p(params, cache_p, tok, ln, bt)
         np.testing.assert_allclose(np.asarray(dp, np.float32),
                                    np.asarray(dc, np.float32),
                                    atol=1e-4, rtol=1e-4)
@@ -178,6 +200,36 @@ def test_engine_paged_is_default_and_token_identical(tiny_model):
     assert got == want
     assert stats.pages_in_use > 0
     assert stats.evictions == 0          # default-ish pool: no pressure
+
+
+def test_step_programs_donate_the_cache(tiny_model):
+    """The engine's step programs update the cache where it lies: each
+    prefill and decode dispatch deletes the cache it was handed (the new
+    one took over its buffers), and the compiled decode program aliases
+    both pool leaves in to out."""
+    m, params, cfg = tiny_model
+    eng = InferenceEngine(m, max_slots=2, max_seq=64, policy="chunked",
+                          prefill_chunk=4, page_size=8)
+    eng.load_params(params)
+    eng.submit(Request(0, np.arange(1, 7, dtype=np.int32), 3,
+                       arrival_s=0.0))
+    st = eng.stats
+    while not (st.prefill_dispatches and st.decode_syncs):
+        held = jax.tree.leaves(eng.cache)
+        dispatched = st.prefill_dispatches + st.decode_syncs
+        eng.step()
+        assert st.prefill_dispatches + st.decode_syncs > dispatched
+        assert all(leaf.is_deleted() for leaf in held)
+    b = eng.max_slots
+    text = eng._jit_decode_paged.lower(
+        params, eng.cache, jnp.zeros((b, 1), jnp.int32),
+        jnp.zeros((b,), jnp.int32), jnp.asarray(eng.allocator.tables),
+        jnp.ones((b,), bool)).compile().as_text()
+    header = text.splitlines()[0]
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                          header)}
+    pool = len(jax.tree.leaves(params))      # the cache follows the params
+    assert {pool, pool + 1} <= aliased, header[:300]
 
 
 def test_engine_eviction_recompute_stays_token_identical(tiny_model):

@@ -64,7 +64,7 @@ def _cases(page: int):
     h, kv, d = ATTN.num_heads, ATTN.num_kv_heads, ATTN.resolved_head_dim
     theta = ATTN.rope_theta
     bf, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
-    pool = ((B * SEQ // page, kv, page, d), bf)
+    pool = ((B * SEQ // page, kv, d, page), bf)
     table = ((B, SEQ // page), i32)
     q_len = SSM.ssm_chunk
     return {
@@ -118,3 +118,52 @@ def test_kernel_compiles_for_v5e(kernel, page, one_chip, no_compile_cache):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_paged"])
+def test_paged_step_updates_the_pool_in_place_on_v5e(program, one_chip,
+                                                     no_compile_cache):
+    """The engine's paged step programs for stablelm-3b at full width,
+    compiled for one v5e: the pool comes in donated and goes out aliased to
+    it, and no pool-sized copy lies between (the layer scan carries the
+    pool, the kernels read it by layer, and its default layout is the one
+    the kernels read)."""
+    import re
+
+    from repro.kernels import ops
+    from repro.models.factory import build_model
+    from repro.serving.engine import InferenceEngine
+    model = build_model(ATTN)
+    pages, page, blocks = 8, 256, 4
+    jitted = getattr(InferenceEngine(model, max_slots=1, max_seq=page,
+                                     kv_pages=1, page_size=page),
+                     f"_jit_{program}")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    shapes = jax.eval_shape(
+        lambda: model.init_paged_cache(pages, page, B, page * blocks))
+    params = model.abstract_params(jnp.bfloat16)
+    i32 = jnp.int32
+    rows = ((B, 1) if program == "decode_paged" else (B, page), i32)
+    args = [params, shapes, jax.ShapeDtypeStruct(*rows),
+            jax.ShapeDtypeStruct((B,), i32),
+            jax.ShapeDtypeStruct((B, blocks), i32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_)]
+    if program == "prefill_paged":
+        args.append(jax.ShapeDtypeStruct((B,), i32))
+    try:
+        ops.set_backend("pallas")
+        text = jitted.lower(*on_chip(args)).compile().as_text()
+    finally:
+        ops.set_backend(None)
+    assert "tpu_custom_call" in text
+    pool = "bf16[%d,%d,%d,%d,%d]" % shapes["k_pages"].shape
+    copies = [line[:120] for line in text.splitlines()
+              if f"= {pool}" in line and re.search(r" copy(-start)?\(", line)]
+    assert not copies
+    n = len(jax.tree.leaves(params))         # the cache follows the params
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, \w+-alias\)",
+                                          text.splitlines()[0])}
+    assert {n, n + 1} <= aliased
