@@ -1,9 +1,7 @@
 """Quantities several metric readers share, computed from what one run
 recorded: the requests a stream's limits judge, and the work of the
-dispatches made inside the measured window."""
+dispatches made inside the measured window, as the cell's block counts it."""
 from __future__ import annotations
-
-from chipbench import flops
 
 
 def judged(ctx) -> list:
@@ -12,35 +10,32 @@ def judged(ctx) -> list:
 
 
 def _work(ctx) -> dict:
-    m = ctx.dims
     rows = list(ctx.dispatches.rows(ctx.first_call, ctx.close_call))
     pre = [(start, len(t)) for kind, _, start, t in rows if kind == "prefill"]
     dec = [start + 1 for kind, _, start, _t in rows if kind == "decode"]
-    pf, pb = flops.prefill_attention(m, pre)
-    df, db = flops.decode_attention(m, dec)
+    step, kernels = ctx.cell.block.work(ctx.cell.config, pre, dec)
     return {"prefill_tokens": sum(c for _, c in pre),
             "decode_tokens": len(dec),
-            "step_flops": flops.step_flops(m, prefill_rows=pre,
-                                           decode_lengths=dec),
-            "prefill_attention": (m.layers * pf, m.layers * pb),
-            "decode_attention": (m.layers * df, m.layers * db)}
+            "step_flops": step,
+            "kernels": kernels}
 
 
 def window_work(ctx) -> dict:
     """Useful work of the window's dispatches: tokens, whole-step FLOPs,
-    and each attention kernel's (FLOPs, bytes) over all layers."""
+    and each kernel's (FLOPs, bytes) over all layers, by kernel name."""
     if not hasattr(ctx, "_work"):
         ctx._work = _work(ctx)
     return ctx._work
 
 
-def roofline_pct(ctx, kernel: str, work_key: str):
+def roofline_pct(ctx, kernel: str):
     """The kernel's least time at the chip's peaks over its device time in
-    the trace, in percent; None where the trace holds no such kernel."""
+    the trace, in percent; None where the trace holds no such kernel, or
+    the cell's block counts no work for it."""
     t = ctx.trace.kernel_s.get(kernel) if ctx.trace else None
     if not t:
         return None
-    fl, by = window_work(ctx)[work_key]
+    fl, by = window_work(ctx)["kernels"].get(kernel, (0, 0))
     if fl == 0 and by == 0:
         return None
     least = max(fl / ctx.peaks["bf16_flops_per_s"],

@@ -4,8 +4,13 @@ and the comparison that decides ``correct``.
 The harness drives ``InferenceEngine.submit()`` and ``step()`` itself on
 the host clock and stamps each token when ``step()`` returns it: ``step()``
 returns only after the device has produced the tokens it emits, so the
-stamps time what a user sees (the engine's own stamps are taken before that
-sync and are not used).
+stamps time what a user sees. The engine's own stamps are not used: its
+decode stamps follow the fetch too, but its prefill stamps time the
+dispatch.
+
+What belongs to the configuration's block (its reference, its work, its
+kernel table and its stacked parameter groups) comes from the cell's block
+module (``spec.load_block``); nothing here names a block.
 """
 from __future__ import annotations
 
@@ -84,11 +89,13 @@ def model_config(config: dict):
 
 def build_model(cell: spec.Cell):
     """The program's model for the cell's configuration, and the shape of
-    its parameter tree."""
+    its parameter tree; a tree that is not the cell's block is refused."""
     import jax.numpy as jnp
     from repro.models.factory import build_model as build
     model = build(model_config(cell.config))
-    return model, model.abstract_params(jnp.bfloat16)
+    abstract = model.abstract_params(jnp.bfloat16)
+    cell.block.check_tree(weights.leaf_specs(abstract))
+    return model, abstract
 
 
 def make_engine(cell: spec.Cell, model, abstract, seed: int):
@@ -98,7 +105,8 @@ def make_engine(cell: spec.Cell, model, abstract, seed: int):
     programs."""
     from repro.serving.engine import InferenceEngine
     params = weights.make_params(abstract, seed,
-                                 int(cell.config["vocab_size"]))
+                                 int(cell.config["vocab_size"]),
+                                 cell.block.STACKED)
     e = cell.traffic["engine"]
     kw = dict(max_slots=int(e["slots"]), max_seq=int(e["max_seq"]),
               policy=e["policy"])
@@ -287,12 +295,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         gc.enable()
     in_window_compiles = closing["compiles"]
     st = _stats_delta(stats0, closing["stats"])
-    reduced = tracer.reduce() if tracer else None
+    reduced = tracer.reduce(cell.block.KERNELS) if tracer else None
     ms = device.memory_stats() or {}
     ctx = SimpleNamespace(
         cell=cell, seed=seed, setup_s=setup_s, window=window,
-        stats=st, peaks=peak, dims=flops.Dense.of(cell.config),
-        prefill_programs=n_widths,
+        stats=st, peaks=peak, prefill_programs=n_widths,
         dispatches=rec, first_call=mark, close_call=closing["call"],
         trace=reduced,
         slo={s.name: s.slo for s in streams if s.slo})
@@ -359,7 +366,6 @@ def check(cell, seed, abstract, engine, rec, mark, window,
     stands in the program's place: at each position where the program
     served a token, the token that the reference computed in float8 puts
     first is the one judged."""
-    from chipbench import reference
     picked, fed, faults = fed_sample(seed, rec, mark, window)
     free(engine)
     gap = 0.0
@@ -367,9 +373,9 @@ def check(cell, seed, abstract, engine, rec, mark, window,
         specs = weights.leaf_specs(abstract)
         seqs = [(fed[r.request_id].tokens, fed[r.request_id].out_positions)
                 for r in picked]
-        ref = reference.logits_at(cell.config, specs, seed, seqs)
+        ref = cell.block.logits_at(cell.config, specs, seed, seqs)
         if control:
-            gaps = correct.control_gaps(ref, reference.logits_at(
+            gaps = correct.control_gaps(ref, cell.block.logits_at(
                 cell.config, specs, seed, seqs, control=True))
         else:
             gaps = correct.served_gaps(picked, ref)

@@ -6,11 +6,12 @@ A trace is first cut down to plain lists (``extract``), the form a test
 keeps on disk: device module executions, device operations and host spans,
 each ``[name, start_ns, end_ns]`` on one clock.
 
-The engine's jitted step programs are all lambdas, so every one of them is
-named ``jit(<lambda>)`` in the trace. A program is told apart by the Pallas
-kernel it holds: one that runs ``paged_prefill_attention`` is a prefill
-step, one that runs ``paged_decode_attention`` a decode step, and any other
-(the cache-slot reset, the argmax) is ``other``.
+The step programs are named after the model's methods
+(``jit(decode_step_paged)``), which say nothing of the block. A program is
+told apart by the kernel it holds, from the cell's block's table
+(``KERNELS``: kernel name -> ``prefill`` or ``decode``): one that runs a
+prefill kernel is a prefill step, one that runs a decode kernel a decode
+step, and any other (the cache-slot reset, the argmax) is ``other``.
 """
 from __future__ import annotations
 
@@ -22,9 +23,6 @@ import pathlib
 import re
 import shutil
 
-#: kernel name (the prefix of its operation's name) -> the step it marks
-KERNELS = {"paged_prefill_attention": "prefill",
-           "paged_decode_attention": "decode"}
 #: host spans the harness writes around its own phases
 WINDOW, WAIT = "bench.window", "bench.wait"
 TOP = 10
@@ -84,9 +82,10 @@ def _op(name: str) -> str:
     return name.split(" = ", 1)[0].lstrip("%")
 
 
-def _kernel_of(name: str):
+def _kernel_of(name: str, kernels: dict):
+    """The kernel of ``kernels`` (name -> step) that an operation runs."""
     name = _op(name)
-    for k in KERNELS:
+    for k in kernels:
         if name.startswith(k):
             return k
     return None
@@ -97,8 +96,9 @@ def _base(name: str) -> str:
     return re.sub(r"\.\d+$", "", _op(name))
 
 
-def reduce(plain: dict) -> Reduced:
-    """Device metrics of the traced window from ``extract``'s lists."""
+def reduce(plain: dict, kernels: dict) -> Reduced:
+    """Device metrics of the traced window from ``extract``'s lists;
+    ``kernels``: the block's kernel name -> the step it marks."""
     wins = [(s, e) for n, s, e in plain["host"] if n == WINDOW]
     if not wins:
         raise ValueError(f"the trace holds no {WINDOW!r} span")
@@ -118,8 +118,8 @@ def reduce(plain: dict) -> Reduced:
             continue
         i, j = bisect.bisect_left(starts, s), bisect.bisect_right(starts, e)
         inside = ops[i:j]
-        kinds = {KERNELS[k] for k in map(_kernel_of, (n for _, _, n in inside))
-                 if k}
+        kinds = {kernels[k] for _, _, n in inside
+                 if (k := _kernel_of(n, kernels))}
         kind = kinds.pop() if len(kinds) == 1 else "other"
         program_s[kind] = program_s.get(kind, 0) + (min(e, hi) - max(s, lo))
         for os_, oe, on in inside:
@@ -127,7 +127,7 @@ def reduce(plain: dict) -> Reduced:
             op_time[key] = op_time.get(key, 0) + (min(oe, hi) - max(os_, lo))
     kernel_s: dict = {}
     for s, e, n in ops:
-        k = _kernel_of(n)
+        k = _kernel_of(n, kernels)
         if k:
             kernel_s[k] = kernel_s.get(k, 0) + (min(e, hi) - max(s, lo))
 
@@ -220,9 +220,9 @@ class Recording:
             raise FileNotFoundError(f"no trace under {self.path}")
         return extract(jax.profiler.ProfileData.from_file(str(found[-1])))
 
-    def reduce(self) -> Reduced:
+    def reduce(self, kernels: dict) -> Reduced:
         try:
-            return reduce(self.load())
+            return reduce(self.load(), kernels)
         finally:
             shutil.rmtree(self.path, ignore_errors=True)
 

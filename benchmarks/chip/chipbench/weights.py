@@ -3,17 +3,18 @@
 float32 reference (one layer at a time).
 
 Each leaf of the program's parameter tree is drawn from a key folded from
-the seed and the leaf's path; a leaf stacked over layers draws layer ``l``
-from that key folded with ``l``. A value is the sum of four random bytes,
-centred (an Irwin-Hall draw, near normal within 3.5 sigma), times a scale
-with few bits: all of it integer arithmetic or exact in float32, so the
-values do not depend on how a compiler fuses the draw, and the reference's
-one-layer draw equals the program's bit for bit. Matrices have a standard
-deviation of 0.0226 (the program's own initialiser uses 0.02); norm scales
-are 1 plus 0.108 of it, so that a norm that ignored its weight would show.
-Rows of the embedding and columns of the head past the true vocabulary (the
-padding up to ``padded_vocab``) are zero: a padded id is never looked up
-and never wins the argmax.
+the seed and the leaf's path; a leaf stacked over layers (one in a group
+that the configuration's block lists as stacked, ``layers/`` for the dense
+block) draws layer ``l`` from that key folded with ``l``. A value is the
+sum of four random bytes, centred (an Irwin-Hall draw, near normal within
+3.5 sigma), times a scale with few bits: all of it integer arithmetic or
+exact in float32, so the values do not depend on how a compiler fuses the
+draw, and the reference's one-layer draw equals the program's bit for bit.
+Matrices have a standard deviation of 0.0226 (the program's own initialiser
+uses 0.02); norm scales are 1 plus 0.108 of it, so that a norm that ignored
+its weight would show. Rows of the embedding and columns of the head past
+the true vocabulary (the padding up to ``padded_vocab``) are zero: a padded
+id is never looked up and never wins the argmax.
 """
 from __future__ import annotations
 
@@ -65,14 +66,11 @@ def _leaf_key(key, name: str):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
 
 
-def stacked(name: str) -> bool:
-    return name.startswith("layers/")
-
-
-def make_params(abstract, seed: int, vocab: int):
+def make_params(abstract, seed: int, vocab: int, stacked: tuple):
     """The whole tree shaped like ``abstract`` (the program's
     ``ShapeDtypeStruct`` tree), made on the default device in one jitted
-    call."""
+    call. ``stacked``: the groups (name prefixes such as ``"layers/"``)
+    whose leaves are stacked over layers and drawn one layer at a time."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     specs = [(leaf_name(p), s.shape, s.dtype) for p, s in flat]
 
@@ -80,7 +78,7 @@ def make_params(abstract, seed: int, vocab: int):
         leaves = []
         for name, shape, dtype in specs:
             k = _leaf_key(key, name)
-            if stacked(name):
+            if name.startswith(tuple(stacked)):
                 leaves.append(jax.vmap(
                     lambda l, k=k, name=name, shape=shape, dtype=dtype: _draw(
                         jax.random.fold_in(k, l), name, shape[1:], dtype,

@@ -6,5 +6,4 @@ from chipbench import derived
 
 
 def read(ctx):
-    return derived.roofline_pct(ctx, "paged_decode_attention",
-                                "decode_attention")
+    return derived.roofline_pct(ctx, "paged_decode_attention")

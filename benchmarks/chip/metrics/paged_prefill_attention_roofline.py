@@ -4,5 +4,4 @@ from chipbench import derived
 
 
 def read(ctx):
-    return derived.roofline_pct(ctx, "paged_prefill_attention",
-                                "prefill_attention")
+    return derived.roofline_pct(ctx, "paged_prefill_attention")

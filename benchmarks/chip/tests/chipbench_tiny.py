@@ -36,8 +36,9 @@ ENGINE = {"slots": 4, "max_seq": 64, "policy": "chunked"}
 
 
 def config(name: str) -> dict:
-    c = {"name": name, "family": "dense", "d_model": 64, "head_dim": 16,
-         "d_ff": 128, "vocab_size": 250, "vocab_pad_multiple": 16,
+    c = {"name": name, "family": "dense", "block": "dense", "d_model": 64,
+         "head_dim": 16, "d_ff": 128, "vocab_size": 250,
+         "vocab_pad_multiple": 16,
          "rope_theta": 10000.0, "norm_eps": 1e-05, "tie_embeddings": False,
          "source": "test", "reduced": [],
          "check": {"max_logit_gap": TINY_GAP}}
@@ -55,12 +56,14 @@ def e2e(name, unit, better, cells=None):
 
 def checkout(tmp: pathlib.Path, streams=(CHAT, AGENT)) -> pathlib.Path:
     """Writes ``BENCHMARK.json`` and ``bench/`` (configs, a traffic mix
-    ``mix``, the real metric readers) under ``tmp``; one cell per tiny
-    config, ``<config>.mix``."""
+    ``mix``, the real blocks and metric readers) under ``tmp``; one cell
+    per tiny config, ``<config>.mix``."""
     bench = tmp / "bench"
     (bench / "configs").mkdir(parents=True)
     (bench / "traffic").mkdir()
-    shutil.copytree(BENCH / "metrics", bench / "metrics")
+    for d in ("blocks", "metrics"):
+        shutil.copytree(BENCH / d, bench / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for name in CONFIGS:
         (bench / "configs" / f"{name}.json").write_text(
             json.dumps(config(name)))

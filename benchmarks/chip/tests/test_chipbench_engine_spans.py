@@ -39,7 +39,7 @@ SPANS = {"engine.step", "engine.admit", "engine.prefill", "engine.decode",
 def test_idle_inside_an_engine_phase_is_charged_to_the_phase():
     plain = {k: [[n, s * US, e * US] for n, s, e in v]
              for k, v in STEPS.items()}
-    r = trace.reduce(plain)
+    r = trace.reduce(plain, spec.load_block("dense").KERNELS)
     assert r.idle_pending_s == pytest.approx(32e-6)
     gaps = dict(r.breakdown["idle_gaps"])
     assert gaps == pytest.approx({"engine.retire": 13e-6,

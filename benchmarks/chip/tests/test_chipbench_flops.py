@@ -1,6 +1,6 @@
-"""FLOP and byte counts against hand counts at the cells' shapes; only live
-rows at their true lengths count; the table of peaks refuses a device kind
-it does not hold."""
+"""FLOP and byte counts of the dense block against hand counts at the
+cells' shapes; only live rows at their true lengths count; the table of
+peaks refuses a device kind it does not hold."""
 import json
 import sys
 import pathlib
@@ -12,12 +12,18 @@ import pytest
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from chipbench import correct, derived, flops  # noqa: E402
+from chipbench import correct, derived, flops, spec  # noqa: E402
+
+dense = spec.load_block("dense")
+
+
+def _cfg(name):
+    with open(BENCH / "configs" / f"{name}.json") as f:
+        return json.load(f)
 
 
 def _dense(name):
-    with open(BENCH / "configs" / f"{name}.json") as f:
-        return flops.Dense.of(json.load(f))
+    return dense.Dense.of(_cfg(name))
 
 
 def test_layer_params_by_hand():
@@ -46,10 +52,10 @@ def test_prefill_attention_by_hand():
 
 def test_step_flops_by_hand():
     m = _dense("stablelm-3b")
-    assert flops.step_flops(m, decode_lengths=[1000]) == (
+    assert dense.step_flops(m, decode_lengths=[1000]) == (
         2 * 79_298_560 * 32 + 32 * 10_240_000 + 2 * 2560 * 50304)
     # prefill tokens pay no unembedding: their logits are never used
-    assert flops.step_flops(m, prefill_rows=[(0, 16)]) == (
+    assert dense.step_flops(m, prefill_rows=[(0, 16)]) == (
         2 * 79_298_560 * 32 * 16 + 32 * flops.prefill_attention(
             m, [(0, 16)])[0])
 
@@ -71,7 +77,8 @@ class _Engine:
 
 
 def test_masked_rows_and_unread_pages_are_not_counted():
-    m = _dense("stablelm-3b")
+    cfg = _cfg("stablelm-3b")
+    m = dense.Dense.of(cfg)
     eng = _Engine(4)
     rec = correct.Dispatches(eng)
     # four rows, two live: their lengths, not the pool's pages, count
@@ -83,13 +90,13 @@ def test_masked_rows_and_unread_pages_are_not_counted():
     eng._jit_prefill_paged(None, None, np.zeros((4, 16), np.int32), None,
                            None, np.array([False, True, False, False]),
                            np.array([0, 8, 0, 0], np.int32))
-    ctx = SimpleNamespace(dims=m, dispatches=rec, first_call=0,
-                          close_call=rec.mark())
+    ctx = SimpleNamespace(cell=SimpleNamespace(block=dense, config=cfg),
+                          dispatches=rec, first_call=0, close_call=rec.mark())
     w = derived.window_work(ctx)
     assert w["decode_tokens"] == 2 and w["prefill_tokens"] == 8
-    assert w["decode_attention"] == tuple(
+    assert w["kernels"]["paged_decode_attention"] == tuple(
         m.layers * x for x in flops.decode_attention(m, [11, 21]))
-    assert w["prefill_attention"] == tuple(
+    assert w["kernels"]["paged_prefill_attention"] == tuple(
         m.layers * x for x in flops.prefill_attention(m, [(500, 8)]))
-    assert w["step_flops"] == flops.step_flops(
+    assert w["step_flops"] == dense.step_flops(
         m, prefill_rows=[(500, 8)], decode_lengths=[11, 21])

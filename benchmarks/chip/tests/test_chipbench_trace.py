@@ -1,5 +1,6 @@
-"""The reduction from a trace to device metrics, on a hand-made trace whose
-answers are counted by hand."""
+"""The reduction from a trace to device metrics, with the dense block's
+kernel table, on a hand-made trace whose answers are counted by hand and on
+a recorded one."""
 import sys
 import pathlib
 
@@ -7,7 +8,9 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
-from chipbench import trace  # noqa: E402
+from chipbench import spec, trace  # noqa: E402
+
+KERNELS = spec.load_block("dense").KERNELS
 
 US = 1000  # the hand-made trace counts in microseconds
 
@@ -31,7 +34,7 @@ def _scaled():
 
 
 def test_hand_counted_trace():
-    r = trace.reduce(_scaled())
+    r = trace.reduce(_scaled(), KERNELS)
     assert r.window_s == pytest.approx(100e-6)
     # busy: [4, 25] + [41, 49] + [60, 62] + [90, 100] (clipped at the close)
     assert r.busy_s == pytest.approx(41e-6)
@@ -55,7 +58,7 @@ def test_hand_counted_trace():
 
 def test_no_window_is_an_error():
     with pytest.raises(ValueError, match="bench.window"):
-        trace.reduce({"host": [], "modules": [], "ops": []})
+        trace.reduce({"host": [], "modules": [], "ops": []}, KERNELS)
 
 
 def test_saved_trace_reads_back(tmp_path):
@@ -70,7 +73,7 @@ def test_recorded_chip_trace():
     the instruction name)."""
     plain = trace.read(pathlib.Path(__file__).parent / "data" /
                        "chat_trace_slice.json.gz")
-    r = trace.reduce(plain)
+    r = trace.reduce(plain, KERNELS)
     assert r.window_s == pytest.approx(0.25)
     assert r.busy_s == pytest.approx(0.241517742)
     assert r.idle_pending_s == pytest.approx(0.008482258)
@@ -88,5 +91,6 @@ def test_recorded_chip_trace():
 
 def test_chip_op_names_are_cut_to_the_instruction():
     assert trace._kernel_of("%paged_prefill_attention.6 = bf16[8,32] "
-                            "custom-call(%a)") == "paged_prefill_attention"
+                            "custom-call(%a)", KERNELS) == \
+        "paged_prefill_attention"
     assert trace._base("%fusion.124 = (f32[8]) fusion(%x)") == "fusion"
