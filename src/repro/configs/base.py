@@ -39,6 +39,24 @@ class ModelConfig:
     num_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
+    # DeepSeek-V3-style routing: "sigmoid" scores, a correction bias used
+    # only to choose the experts, the chosen scores normalised and scaled
+    # by ``routed_scaling_factor``, and no token dropped (no capacity);
+    # "softmax" is the GShard-style router (capacity-bounded in training;
+    # served dropless like "sigmoid")
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # the experts this device holds of each expert layer, the first
+    # ``experts_held`` of the router's ``num_experts`` (the chip's share of
+    # an expert-parallel deployment); 0 holds them all
+    experts_held: int = 0
+    # leading layers with a dense MLP of ``d_ff`` before the expert layers
+    first_k_dense_replace: int = 0
+    # --- latent attention (MLA, DeepSeek-V2/V3; no q LoRA) ---
+    kv_lora_rank: int = 0         # latent width; 0 -> standard attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (Mamba2 SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -70,6 +88,21 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts held here, of each expert layer."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one cached MLA token: the latent and the shared
+        rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def is_attention_free(self) -> bool:
@@ -105,10 +138,19 @@ class ModelConfig:
             return []
         if self.moe_every:
             return [i for i in range(self.num_layers) if i % self.moe_every == self.moe_every - 1]
-        return list(range(self.num_layers))
+        return list(range(self.first_k_dense_replace, self.num_layers))
 
     # ------------------------------------------------------------ param math
     def _attn_params(self) -> int:
+        if self.is_mla:
+            h = self.num_heads
+            q = self.d_model * h * (self.qk_nope_head_dim +
+                                    self.qk_rope_head_dim)
+            kv_a = self.d_model * self.latent_width + self.kv_lora_rank
+            kv_b = self.kv_lora_rank * h * (self.qk_nope_head_dim +
+                                            self.v_head_dim)
+            o = h * self.v_head_dim * self.d_model
+            return q + kv_a + kv_b + o
         hd = self.resolved_head_dim
         q = self.d_model * self.num_heads * hd
         kv = 2 * self.d_model * self.num_kv_heads * hd
@@ -124,8 +166,10 @@ class ModelConfig:
         """(total, active) params of one MoE layer's expert stack + router."""
         per_expert = self._mlp_params(self.moe_d_ff)
         router = self.d_model * self.num_experts
+        if self.scoring_func == "sigmoid":
+            router += self.num_experts        # the correction bias
         shared = self.num_shared_experts * per_expert
-        total = self.num_experts * per_expert + router + shared
+        total = self.held_experts * per_expert + router + shared
         active = self.num_experts_per_token * per_expert + router + shared
         return total, active
 
@@ -200,7 +244,11 @@ class ModelConfig:
         )
         if self.is_moe:
             kw.update(num_experts=4, num_experts_per_token=2, moe_d_ff=64,
-                      num_shared_experts=min(self.num_shared_experts, 1))
+                      num_shared_experts=min(self.num_shared_experts, 1),
+                      experts_held=min(self.experts_held, 4))
+        if self.is_mla:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                      qk_rope_head_dim=8, v_head_dim=16)
         if self.family in ("ssm", "hybrid"):
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
         if self.family == "hybrid":

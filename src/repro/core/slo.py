@@ -106,7 +106,9 @@ class SLOReport:
         if not lat:
             return {}
         a = np.asarray(lat)
-        return {"mean": float(a.mean()), "p50": float(np.percentile(a, 50)),
+        # a float sum can round the mean of equal values past them
+        mean = float(np.clip(a.mean(), a.min(), a.max()))
+        return {"mean": mean, "p50": float(np.percentile(a, 50)),
                 "p95": float(np.percentile(a, 95)),
                 "p99": float(np.percentile(a, 99)), "max": float(a.max()),
                 "n": len(a)}

@@ -22,6 +22,10 @@ from repro.kernels.decode_attention import decode_attention as _pallas_decode
 from repro.kernels.flash_attention import flash_attention as _pallas_flash
 from repro.kernels.paged_decode_attention import \
     paged_decode_attention as _pallas_paged_decode
+from repro.kernels.paged_mla_decode_attention import \
+    paged_mla_decode_attention as _pallas_paged_mla_decode
+from repro.kernels.paged_mla_prefill_attention import \
+    paged_mla_prefill_attention as _pallas_paged_mla_prefill
 from repro.kernels.paged_prefill_attention import \
     paged_prefill_attention as _pallas_paged_prefill
 from repro.kernels.prefill_attention import \
@@ -152,6 +156,51 @@ def attention_prefill_chunk_paged(q, k_pages, v_pages, block_tables,
                               rope_theta=rope_theta,
                               interpret=(be == "interpret"))
     return o.transpose(0, 2, 1, 3)
+
+
+def _latent_rows(pool, layer, block_tables):
+    from repro.models.attention import gather_pages, layer_pages
+    return gather_pages(layer_pages(pool, layer), block_tables)[:, :, 0]
+
+
+def attention_mla_decode_paged(q, pool, block_tables, lengths, *,
+                               scale: float, rank: int, layer=0):
+    """q: (B, 1, H, W) absorbed, rotated queries; pool: (L, P, 1, W, page)
+    latent pages read at ``layer``, or one layer's slab; block_tables:
+    (B, nb); lengths (B,) keys per row -> (B, 1, H, rank) float32, each
+    head's weighted latent."""
+    be = backend()
+    if be == "jnp":
+        from repro.models.mla import attend
+        rows = _latent_rows(pool, layer, block_tables)
+        return attend(q, rows, jnp.asarray(lengths)[:, None] - 1, scale,
+                      rank)
+    o = _pallas_paged_mla_decode(q[:, 0], pool,
+                                 jnp.asarray(block_tables, jnp.int32),
+                                 jnp.asarray(lengths, jnp.int32), layer,
+                                 scale=scale, rank=rank,
+                                 interpret=(be == "interpret"))
+    return o[:, None]
+
+
+def attention_mla_prefill_paged(q, pool, block_tables, start_len, *,
+                                scale: float, rank: int, layer=0):
+    """q: (B, C, H, W) absorbed, rotated queries of a chunk whose latent
+    rows are already in the pool at ``start_len ..``; pool as in
+    :func:`attention_mla_decode_paged` -> (B, C, H, rank) float32."""
+    be = backend()
+    b, c, h, w = q.shape
+    if be == "jnp":
+        from repro.models.mla import attend
+        rows = _latent_rows(pool, layer, block_tables)
+        qpos = jnp.asarray(start_len)[:, None] + jnp.arange(c)[None, :]
+        return attend(q, rows, qpos, scale, rank)
+    o = _pallas_paged_mla_prefill(q.reshape(b, c * h, w), pool,
+                                  jnp.asarray(block_tables, jnp.int32),
+                                  jnp.asarray(start_len, jnp.int32), layer,
+                                  heads=h, scale=scale, rank=rank,
+                                  interpret=(be == "interpret"))
+    return o.reshape(b, c, h, rank)
 
 
 def ssd_intra_chunk(x, dt, cum, b_, c_):

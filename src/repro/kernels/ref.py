@@ -126,6 +126,49 @@ def paged_prefill_attention_ref(q: Array, k_pages: Array, v_pages: Array,
                                  start_len, rope_theta=rope_theta)
 
 
+def _latent_rows(pool: Array, block_tables: Array) -> Array:
+    """A latent pool (P, 1, W, page) gathered per row -> (B, nb*page, W)."""
+    g = pool[block_tables][:, :, 0]                 # (B, nb, W, page)
+    b, nb, w, page = g.shape
+    return g.transpose(0, 1, 3, 2).reshape(b, nb * page, w)
+
+
+def _latent_attention(q: Array, rows: Array, qpos: Array, scale: float,
+                      rank: int) -> Array:
+    """q (B, R, W) against rows (B, S, W); query r sees rows up to
+    ``qpos[b, r]``; values are the first ``rank`` columns -> (B, R, rank)."""
+    rows = rows.astype(jnp.float32)
+    logits = jnp.einsum("brw,bsw->brs", q.astype(jnp.float32), rows) * scale
+    valid = jnp.arange(rows.shape[1])[None, None, :] <= qpos[:, :, None]
+    p = jax.nn.softmax(jnp.where(valid, logits, NEG_INF), axis=-1)
+    return jnp.einsum("brs,bsv->brv", p, rows[..., :rank])
+
+
+def paged_mla_decode_attention_ref(q: Array, pool: Array,
+                                   block_tables: Array, lengths: Array, *,
+                                   scale: float, rank: int) -> Array:
+    """Paged latent-attention decode oracle. q: (B, H, W) absorbed queries;
+    pool: (P, 1, W, page); block_tables: (B, nb); lengths: (B,) keys per
+    row. Every head attends the row's first ``lengths`` latent rows, and
+    the values are their first ``rank`` columns. -> (B, H, rank)."""
+    qpos = jnp.broadcast_to((lengths - 1)[:, None], q.shape[:2])
+    return _latent_attention(q, _latent_rows(pool, block_tables), qpos,
+                             scale, rank)
+
+
+def paged_mla_prefill_attention_ref(q: Array, pool: Array,
+                                    block_tables: Array, start_len: Array,
+                                    *, heads: int, scale: float,
+                                    rank: int) -> Array:
+    """Paged latent-attention prefill oracle. q: (B, C*H, W) token-major
+    (row r is token ``r // heads``); the pool already holds the chunk's
+    rows at ``start_len ..``; query row r sees positions up to
+    ``start_len + r // heads``. -> (B, C*H, rank)."""
+    qpos = start_len[:, None] + jnp.arange(q.shape[1])[None, :] // heads
+    return _latent_attention(q, _latent_rows(pool, block_tables), qpos,
+                             scale, rank)
+
+
 def ssd_chunk_ref(x: Array, dt: Array, cum: Array, b_: Array, c_: Array) -> tuple[Array, Array]:
     """Intra-chunk SSD + end-of-chunk state, one chunk.
 

@@ -74,7 +74,10 @@ class ModelBundle:
     # ------------------------------------------------------ paged serving
     #: cache leaves that live in the shared page pool (no batch axis);
     #: slot-slicing helpers pass them through untouched
-    PAGE_KEYS = ("k_pages", "v_pages")
+    PAGE_KEYS = ("k_pages", "v_pages", "latent_pages")
+    #: every cache leaf with no slot axis: the pools, and an expert model's
+    #: routing counters (``moe_counts``), which no page copy touches
+    SHARED_KEYS = PAGE_KEYS + ("moe_counts",)
 
     def cache_pages(self) -> bool:
         """Does this family support the paged KV cache? True for every
@@ -92,14 +95,13 @@ class ModelBundle:
         """Can finished requests' prompt KV be reused across requests
         (radix prefix cache)? Requires the ENTIRE prefill state to live in
         the page pool, so mapping a donor's pages reproduces the donor's
-        state bit-exactly: true for pure dense transformers (incl. VLM
-        text stacks). Hybrid keeps slot-resident SSM state and enc-dec
-        keeps slot-resident cross-KV — pages alone don't carry their
-        prefill state; MoE routing is batch-coupled (capacity drops), so
-        a donor's KV is not what a fresh prefill would compute."""
+        state bit-exactly: true for the transformer families (dense, VLM
+        text stacks, and MoE, whose served expert layers drop no token, so
+        a row's KV never depends on its batch-mates). Hybrid keeps
+        slot-resident SSM state and enc-dec keeps slot-resident cross-KV —
+        pages alone don't carry their prefill state."""
         return (self.cache_pages()
-                and self.cfg.family in ("dense", "vlm")
-                and not self.cfg.is_moe)
+                and self.cfg.family in ("dense", "vlm", "moe"))
 
     def copy_page(self, cache: Cache, src, dst) -> Cache:
         """Device copy of one pool row across every paged layer — the CoW
@@ -239,9 +241,9 @@ class ModelBundle:
         (multi-slot batched prefill: one dispatch advances several
         mid-prefill slots by different amounts, pads at the tail). Pad
         tokens never touch the cache/state; their logits are garbage the
-        engine discards. Only meaningful when
-        :meth:`multi_slot_batchable` — MoE routing is batch-coupled, so
-        batching rows there would change valid rows' outputs.
+        engine discards. Every family's rows are independent here (served
+        expert layers drop no token), so batched rows compute what
+        per-row dispatches would.
         """
         f = self.cfg.family
         if f == "ssm":
@@ -262,21 +264,6 @@ class ModelBundle:
                                                     active, valid)
         new = jax.tree.map(lambda n, o: n.astype(o.dtype), new, cache)
         return logits, new
-
-    def multi_slot_batchable(self) -> bool:
-        """Can ``prefill_chunk(valid=...)`` batch SEVERAL mid-prefill slots
-        into one dispatch without changing any row's tokens? True for every
-        family whose per-token compute is row-independent. False when MoE
-        routing is present (dense MoE, or hybrid with ``moe_every > 0``):
-        expert capacity is assigned by a cumulative sum over ALL tokens in
-        the flattened batch, so co-batched rows change which of a row's
-        tokens get dropped — the engine falls back to per-slot dispatches
-        to keep token streams bit-identical."""
-        if self.cfg.is_moe:
-            return False
-        if self.cfg.family == "hybrid" and self.cfg.moe_every > 0:
-            return False
-        return True
 
     # ---------------------------------------------------------- dry-run IO
     def input_specs(self, shape: ShapeConfig) -> dict:
@@ -317,7 +304,7 @@ class ModelBundle:
         whole, so a slice of a paged cache still zips against it in
         :meth:`set_cache_slice`."""
         def one(path, leaf):
-            if self._leaf_key(path) in self.PAGE_KEYS:
+            if self._leaf_key(path) in self.SHARED_KEYS:
                 return leaf
             ax = self._cache_batch_axis(path)
             return jax.lax.slice_in_dim(leaf, slot, slot + 1, axis=ax)
@@ -327,7 +314,7 @@ class ModelBundle:
         """Write a per-slot piece back; page-pool leaves are left untouched
         (slot admission remaps block tables instead of copying pages)."""
         def one(path, leaf, pleaf):
-            if self._leaf_key(path) in self.PAGE_KEYS:
+            if self._leaf_key(path) in self.SHARED_KEYS:
                 return leaf
             ax = self._cache_batch_axis(path)
             return jax.lax.dynamic_update_slice_in_dim(

@@ -69,9 +69,13 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
 
 
 def _sub_ffn(sub: dict, x: Array, cfg: ModelConfig,
-             token_mask: Array | None = None):
+             token_mask: Array | None = None, serving: bool = False):
+    """(y, aux). ``serving``: expert sublayers run dropless (see
+    ``moe.moe_dispatch``), so a row's output never depends on its
+    batch-mates."""
     if "moe" in sub:
-        return moe.moe_dispatch(sub["moe"], x, cfg, token_mask)
+        return moe.moe_dispatch(sub["moe"], x, cfg, token_mask,
+                                dropless=serving)[:2]
     return layers.mlp(sub["mlp"], x), jnp.zeros((), jnp.float32)
 
 
@@ -188,7 +192,7 @@ def decode_step(params: dict, cache: dict, tokens: Array, lengths: Array,
                 si += 1
             x = x + out
             h2 = layers.rmsnorm(x, sub["ln2"], cfg.norm_eps)
-            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active)
+            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active, serving=True)
             x = x + f
         stacked = jax.tree.map(lambda *a: jnp.stack(a), *new_states)
         return x, (kc, vc, stacked)
@@ -228,7 +232,7 @@ def decode_step_paged(params: dict, cache: dict, tokens: Array,
                 si += 1
             x = x + out
             h2 = layers.rmsnorm(x, sub["ln2"], cfg.norm_eps)
-            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active)
+            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active, serving=True)
             x = x + f
         stacked = jax.tree.map(lambda *a: jnp.stack(a), *new_states)
         return x, (kp, vp, stacked)
@@ -248,6 +252,7 @@ def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
     """Paged batched chunked prefill; see :func:`prefill_chunk`."""
     x = layers.embed(params["embedding"], tokens)
     pcount = _period(cfg)
+    live = transformer._live(tokens, active, valid)
 
     def body(x, inp):
         bp, kp, vp, states = inp
@@ -272,7 +277,8 @@ def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
                 si += 1
             x = x + out
             h2 = layers.rmsnorm(x, sub["ln2"], cfg.norm_eps)
-            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active)
+            f, _ = _sub_ffn(sub, h2, cfg, token_mask=live,
+                            serving=True)
             x = x + f
         stacked = jax.tree.map(lambda *a: jnp.stack(a), *new_states)
         return x, (kp, vp, stacked)
@@ -300,6 +306,7 @@ def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
     """
     x = layers.embed(params["embedding"], tokens)
     pcount = _period(cfg)
+    live = transformer._live(tokens, active, valid)
 
     def body(x, inp):
         bp, kc, vc, states = inp
@@ -323,7 +330,8 @@ def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
                 si += 1
             x = x + out
             h2 = layers.rmsnorm(x, sub["ln2"], cfg.norm_eps)
-            f, _ = _sub_ffn(sub, h2, cfg, token_mask=active)
+            f, _ = _sub_ffn(sub, h2, cfg, token_mask=live,
+                            serving=True)
             x = x + f
         stacked = jax.tree.map(lambda *a: jnp.stack(a), *new_states)
         return x, (kc, vc, stacked)

@@ -1,4 +1,18 @@
-"""GShard-style top-k MoE with capacity-bounded scatter dispatch.
+"""Two expert layers: a held-share dropless layer (serving, and every
+sigmoid-routed model) and GShard-style top-k MoE with capacity-bounded
+scatter dispatch (training of softmax-routed models).
+
+The held-share layer (:func:`moe_held`) routes each token over all
+``num_experts`` at the router's published width, computes only the picks
+that land on the experts this device holds (``[first, first +
+held_experts)``; shard 0 of a deployment holds the first) with grouped
+matmuls over them, and
+drops no token: a row's output never depends on its batch-mates. On one
+chip of an expert-parallel deployment it runs without its exchange; the
+shares of all chips, with the shared experts counted once, add up to the
+whole layer.
+
+The capacity-bounded layer:
 
 Dispatch is expressed as k scatter/gather pairs between the token-sharded
 activation layout (tokens on the "data"/"pod" axes) and the expert-sharded
@@ -22,13 +36,18 @@ Array = jax.Array
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
+    held = cfg.held_experts
     ks = layers.split_keys(key, ["router", "gate", "up", "down", "shared"])
     params = {
         "router": layers.dense_init(ks["router"], (d, e), dtype=jnp.float32),
-        "w_gate": layers.dense_init(ks["gate"], (e, d, f), dtype=dtype),
-        "w_up": layers.dense_init(ks["up"], (e, d, f), dtype=dtype),
-        "w_down": layers.dense_init(ks["down"], (e, f, d), dtype=dtype),
+        "w_gate": layers.dense_init(ks["gate"], (held, d, f), dtype=dtype),
+        "w_up": layers.dense_init(ks["up"], (held, d, f), dtype=dtype),
+        "w_down": layers.dense_init(ks["down"], (held, f, d), dtype=dtype),
     }
+    if cfg.scoring_func == "sigmoid":
+        # the correction bias: it moves which experts are chosen, never the
+        # weight a chosen expert's output is given
+        params["router_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.num_shared_experts:
         params["shared"] = layers.init_mlp(
             ks["shared"], d, f * cfg.num_shared_experts, dtype=dtype)
@@ -219,13 +238,112 @@ def moe_ffn_shardmap(params: dict, x: Array, cfg: ModelConfig):
 
 
 def moe_dispatch(params: dict, x: Array, cfg: ModelConfig,
-                 token_mask: Array | None = None):
-    """Entry point honoring the hints.moe_impl knob.
+                 token_mask: Array | None = None, *, dropless: bool = False):
+    """Entry point: ``(y, aux, counts)``, ``counts`` as :func:`moe_held`
+    returns them (zeros from the capacity path).
 
-    ``token_mask`` (serving active-slot mask) forces the scatter path — the
-    shard_map variant is a train/prefill optimization and never sees decode
-    batches with dead rows (autotune table: shardmap loses on decode)."""
+    Sigmoid-routed models, and every model the engine serves
+    (``dropless``), take the held-share dropless layer. Otherwise the
+    capacity-bounded layer, honoring the hints.moe_impl knob (the
+    shard_map form is of the capacity-bounded layer only)."""
+    if dropless or cfg.scoring_func == "sigmoid":
+        return moe_held(params, x, cfg, token_mask)
     from repro.distributed import hints
     if token_mask is None and hints.get("moe_impl") == "shardmap":
-        return moe_ffn_shardmap(params, x, cfg)
-    return moe_ffn(params, x, cfg, token_mask)
+        y, aux = moe_ffn_shardmap(params, x, cfg)
+    else:
+        y, aux = moe_ffn(params, x, cfg, token_mask)
+    return y, aux, jnp.zeros((3,), jnp.int32)
+
+
+# --------------------------------------------------------------------------
+# held-share dropless layer
+# --------------------------------------------------------------------------
+
+def route(params: dict, xf: Array, cfg: ModelConfig):
+    """Each token's ``num_experts_per_token`` experts over all
+    ``num_experts`` and the weight of each, ``(T, k)`` ids and float32
+    weights, and the float32 scores. Sigmoid scoring chooses by score plus
+    the correction bias and weighs by the chosen scores, normalised and
+    scaled by ``routed_scaling_factor``; softmax scoring chooses and weighs
+    by the normalised top-k probabilities.
+
+    The router's product is float32 at full precision, as the published
+    DeepSeek-V3 gate computes it (a TPU's default float32 product rounds
+    both operands to bfloat16, which flips near-tied picks)."""
+    logits = jnp.matmul(xf.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    k = cfg.num_experts_per_token
+    if cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, eids = jax.lax.top_k(scores + params["router_bias"], k)
+        w = jnp.take_along_axis(scores, eids, axis=1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        w, eids = jax.lax.top_k(scores, k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return eids, w, scores
+
+
+def moe_held(params: dict, x: Array, cfg: ModelConfig,
+             token_mask: Array | None = None, first: int = 0):
+    """x: (B, S, D) -> (y, aux, counts), dropless, over the held experts.
+
+    Every live token is routed over all ``num_experts``; its picks that
+    land in ``[first, first + held)`` are sorted by expert and computed by
+    grouped matmuls (``lax.ragged_dot``) over the held experts' weights,
+    with no capacity: nothing is dropped and a token's output does not
+    depend on the other tokens. Then the shared experts are added, for every token.
+
+    ``token_mask``: optional (B,) bool row mask, or (B, S) token mask;
+    tokens outside it are routed nowhere (their outputs are garbage the
+    caller ignores) and not counted.
+    ``counts``: int32 ``(picks, held picks, the busiest held expert's
+    picks)`` of the live tokens. ``aux``: the sequence-wise balance loss of
+    DeepSeek-V3 over the batch (1 when balanced)."""
+    b, s, d = x.shape
+    t = b * s
+    k = cfg.num_experts_per_token
+    e = cfg.num_experts
+    held = params["w_gate"].shape[0]
+    xf = x.reshape(t, d)
+    eids, w, scores = route(params, xf, cfg)
+    live = (jnp.ones((t,), bool) if token_mask is None else
+            jnp.broadcast_to(token_mask.reshape(b, -1), (b, s)).reshape(t))
+    rel = eids - first
+    mine = (rel >= 0) & (rel < held) & live[:, None]              # (T, k)
+    group = jnp.where(mine, rel, held).reshape(-1)                 # (T*k,)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    xs = xf[order // k]                                            # (T*k, D)
+
+    def experts(xs, wg, wu, wd, sizes):
+        g = jax.lax.ragged_dot(xs, wg, sizes)
+        u = jax.lax.ragged_dot(xs, wu, sizes)
+        return jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(xs.dtype), wd,
+                                  sizes)
+
+    mesh = _ambient_mesh_axes()
+    if mesh is not None and jax.sharding.AxisType.Explicit in mesh.axis_types:
+        # ragged_dot has no sharding rule under explicit mesh axes
+        from jax.sharding import PartitionSpec as P
+        experts = jax.sharding.auto_axes(experts, out_sharding=P())
+    ys = experts(xs, params["w_gate"], params["w_up"], params["w_down"],
+                 sizes)
+    # rows past the held picks are in no group: zero them, then put each
+    # pick back in its token's place
+    ys = jnp.where((jnp.arange(t * k) < jnp.sum(sizes))[:, None], ys, 0)
+    yk = jnp.zeros_like(ys).at[order].set(ys).reshape(t, k, d)
+    wk = jnp.where(mine, w, 0.0).astype(x.dtype)
+    y = jnp.einsum("tkd,tk->td", yk, wk)
+    if "shared" in params:
+        y = y + layers.mlp(params["shared"], xf)
+    counts = jnp.stack([k * jnp.sum(live), jnp.sum(mine),
+                        jnp.max(sizes)]).astype(jnp.int32)
+    # balance: E/k * sum_e (share of picks) * (mean normalised score)
+    picks = jnp.zeros((e,), jnp.float32).at[eids.reshape(-1)].add(1.0)
+    p_e = jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=0)
+    aux = e / k * jnp.sum(picks / t * p_e)
+    return y.reshape(b, s, d), aux, counts
+

@@ -1,12 +1,23 @@
 """Decoder-only transformer LM (dense / moe / vlm families).
 
 Parameters are a nested dict with all per-layer leaves stacked on a leading
-layer axis; the forward pass is a single ``lax.scan`` over layers with a
+layer axis; the forward pass is a ``lax.scan`` over layers with a
 configurable remat policy, so the lowered HLO stays compact at any depth
 (61-layer kimi-k2 lowers to the same module size as 22-layer tinyllama).
+An expert model's leading dense layers (``first_k_dense_replace``) are a
+group of their own, ``dense_layers``, scanned before ``layers``; caches
+stay indexed by absolute layer.
+
+Attention is grouped-query, or latent (MLA, :mod:`repro.models.mla`) where
+the config has a ``kv_lora_rank``: then the cache holds one latent row a
+token (``latent``, paged ``latent_pages``) in place of K and V. Expert
+layers serve through the held-share dropless layer
+(:func:`repro.models.moe.moe_held`), and a paged cache of an expert model
+carries ``moe_counts``, the routing counters summed on the device.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -14,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers, moe
+from repro.models import layers, mla, moe
 from repro.models.attention import (decode_attention_jnp, flash_attention_jnp,
                                     gather_pages, layer_pages,
                                     naive_attention,
@@ -27,6 +38,8 @@ FLASH_MIN_SEQ = 2048
 # ----------------------------------------------------------------- params
 
 def init_attn(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+    if cfg.is_mla:
+        return mla.init_mla(key, cfg, dtype)
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     ks = layers.split_keys(key, ["q", "k", "v", "o"])
     p = {
@@ -41,23 +54,32 @@ def init_attn(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     return p
 
 
-def init_layer(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+def init_layer(key, cfg: ModelConfig, dtype=jnp.float32,
+               experts: bool | None = None) -> dict:
+    """One layer; its FFN holds experts where ``experts`` (default: the
+    config is an expert model), else it is the dense MLP of ``d_ff``."""
     ks = layers.split_keys(key, ["attn", "ffn"])
     p = {
         "ln1": jnp.ones((cfg.d_model,), jnp.float32),
         "ln2": jnp.ones((cfg.d_model,), jnp.float32),
         "attn": init_attn(ks["attn"], cfg, dtype),
     }
-    if cfg.is_moe:
+    if cfg.is_moe if experts is None else experts:
         p["ffn"] = moe.init_moe(ks["ffn"], cfg, dtype)
     else:
         p["ffn"] = layers.init_mlp(ks["ffn"], cfg.d_model, cfg.d_ff, dtype)
     return p
 
 
+def _dense_first(cfg: ModelConfig) -> int:
+    """Leading dense layers of an expert model."""
+    return cfg.first_k_dense_replace if cfg.is_moe else 0
+
+
 def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
     ks = layers.split_keys(key, ["emb", "head", "layers"])
-    lkeys = jax.random.split(ks["layers"], cfg.num_layers)
+    n_dense = _dense_first(cfg)
+    lkeys = jax.random.split(ks["layers"], cfg.num_layers - n_dense)
     stacked = jax.vmap(lambda k: init_layer(k, cfg, dtype))(lkeys)
     params = {
         "embedding": layers.init_embedding(ks["emb"], cfg.padded_vocab,
@@ -65,6 +87,11 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
         "layers": stacked,
         "ln_f": jnp.ones((cfg.d_model,), jnp.float32),
     }
+    if n_dense:
+        dkeys = jax.random.split(jax.random.fold_in(ks["layers"], 1),
+                                 n_dense)
+        params["dense_layers"] = jax.vmap(
+            lambda k: init_layer(k, cfg, dtype, experts=False))(dkeys)
     if not cfg.tie_embeddings:
         params["lm_head"] = layers.dense_init(
             ks["head"], (cfg.d_model, cfg.padded_vocab), dtype=dtype)
@@ -298,6 +325,79 @@ def _write_chunk(pages: Array, layer, block_tables: Array, start: Array,
     return pool if pages.ndim == 5 else pool[0]
 
 
+def mla_decode_block_paged(p: dict, x: Array, cfg: ModelConfig, pool: Array,
+                           block_tables: Array, lengths: Array,
+                           active: Array | None = None,
+                           layer: Array | int = 0):
+    """One-token latent attention against the PAGED latent pool, in
+    absorbed form: the token's latent row is written as one column of the
+    page covering ``lengths`` (as :func:`attention_decode_block_paged`
+    writes K), and every head attends the row's latent pages
+    (``ops.attention_mla_decode_paged``). pool: (L, P, 1, W, page) at
+    ``layer``, or one layer's slab."""
+    q_abs, lat = mla.project(p, x, cfg, lengths[:, None])
+    page = pool.shape[-1]
+    block = jnp.minimum(lengths // page, block_tables.shape[1] - 1)
+    pidx = jnp.take_along_axis(block_tables, block[:, None], axis=1)[:, 0]
+    write = jnp.ones(lengths.shape, bool) if active is None else active
+    pool = _write_tokens(pool, layer, pidx, lengths % page, lat[:, :1],
+                         write)
+    from repro.kernels import ops
+    o = ops.attention_mla_decode_paged(q_abs, pool, block_tables,
+                                       lengths + 1, scale=mla.scale(cfg),
+                                       rank=cfg.kv_lora_rank, layer=layer)
+    return mla.out(p, o, cfg, x.dtype), pool
+
+
+def mla_prefill_chunk_block_paged(p: dict, x: Array, cfg: ModelConfig,
+                                  pool: Array, block_tables: Array,
+                                  start_len: Array,
+                                  active: Array | None = None,
+                                  valid: Array | None = None,
+                                  layer: Array | int = 0):
+    """Chunked-prefill latent attention against the PAGED latent pool: the
+    chunk's latent rows are written into the rows' pages (pad tokens under
+    ``valid`` write nothing), then the absorbed queries attend causally
+    (``ops.attention_mla_prefill_paged``)."""
+    b, c, _ = x.shape
+    positions = start_len[:, None] + jnp.arange(c)[None, :]
+    q_abs, lat = mla.project(p, x, cfg, positions)
+    write = jnp.ones((b, c), bool)
+    if active is not None:
+        write = write & active[:, None]
+    if valid is not None:
+        write = write & (jnp.arange(c)[None, :] < valid[:, None])
+    pool = _write_chunk(pool, layer, block_tables, start_len,
+                        lat[:, :, None, :], write)
+    from repro.kernels import ops
+    o = ops.attention_mla_prefill_paged(q_abs, pool, block_tables,
+                                        start_len, scale=mla.scale(cfg),
+                                        rank=cfg.kv_lora_rank, layer=layer)
+    return mla.out(p, o, cfg, x.dtype), pool
+
+
+def mla_chunk_block(p: dict, x: Array, cfg: ModelConfig, cache: Array,
+                    start_len: Array, active: Array | None = None,
+                    valid: Array | None = None):
+    """Latent attention of C new tokens against a contiguous (B, S, W)
+    latent cache: the rows are written at ``start_len ..`` (inactive rows
+    and pad tokens dropped, as in :func:`attention_prefill_chunk_block`),
+    then attended causally. C = 1 is a decode step."""
+    b, c, _ = x.shape
+    s = cache.shape[1]
+    positions = start_len[:, None] + jnp.arange(c)[None, :]
+    q_abs, lat = mla.project(p, x, cfg, positions)
+    w_pos = positions if active is None else \
+        jnp.where(active[:, None], positions, jnp.int32(s))
+    if valid is not None:
+        w_pos = jnp.where(jnp.arange(c)[None, :] < valid[:, None], w_pos,
+                          jnp.int32(s))
+    cache = cache.at[jnp.arange(b)[:, None], w_pos].set(
+        lat.astype(cache.dtype), mode="drop")
+    o = mla.attend(q_abs, cache, positions, mla.scale(cfg), cfg.kv_lora_rank)
+    return mla.out(p, o, cfg, x.dtype), cache
+
+
 def _chunk_attend(p: dict, q: Array, k_full: Array, v_full: Array,
                   positions: Array, cfg: ModelConfig, x_dtype) -> Array:
     """Chunk-vs-cache causal attention shared by the contiguous and paged
@@ -418,10 +518,40 @@ def attention_prefill_chunk_block(p: dict, x: Array, cfg: ModelConfig,
 
 
 def _ffn(p: dict, x: Array, cfg: ModelConfig,
-         token_mask: Array | None = None):
-    if cfg.is_moe:
-        return moe.moe_dispatch(p, x, cfg, token_mask)
-    return layers.mlp(p, x), jnp.zeros((), jnp.float32)
+         token_mask: Array | None = None, serving: bool = False):
+    """(y, aux, routing counts); the layer's params say whether it holds
+    experts. ``serving``: experts run dropless (see ``moe.moe_dispatch``)."""
+    if "router" in p:
+        return moe.moe_dispatch(p, x, cfg, token_mask, dropless=serving)
+    return (layers.mlp(p, x), jnp.zeros((), jnp.float32),
+            jnp.zeros((3,), jnp.int32))
+
+
+def _scan_layers(body, carry, params: dict, per_layer=()):
+    """``body(carry, (lp, l, slices)) -> (carry, ys)`` over every layer:
+    the leading dense group (``dense_layers``) first, then ``layers``.
+    ``l`` is the absolute layer index, and ``per_layer`` (arrays stacked
+    over all layers, such as a contiguous cache) is sliced to each group.
+    Returns the last carry and the ys stacked over all layers."""
+    outs, lo = [], 0
+    for name in ("dense_layers", "layers"):
+        if name not in params:
+            continue
+        group = params[name]
+        n = jax.tree.leaves(group)[0].shape[0]
+        xs = (group, jnp.arange(lo, lo + n),
+              tuple(a[lo:lo + n] for a in per_layer))
+        carry, ys = layers.scan(body, carry, xs)
+        outs.append(ys)
+        lo += n
+    if len(outs) == 1:
+        return carry, outs[0]
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+
+
+def _pool_keys(cfg: ModelConfig) -> tuple:
+    """The paged cache's pools, in the order the layer scan carries them."""
+    return ("latent_pages",) if cfg.is_mla else ("k_pages", "v_pages")
 
 
 def _logits(params: dict, x: Array, cfg: ModelConfig) -> Array:
@@ -463,28 +593,34 @@ def forward(params: dict, tokens: Array, cfg: ModelConfig, *,
     """tokens: (B, S) int32 (or ``embeds``: (B,S,D) for frontend stubs).
 
     Returns (logits, aux_loss) or (logits, aux_loss, cache) with
-    cache = {"k": (L,B,S,KV,hd), "v": ...} when ``return_cache``.
+    cache = {"k": (L,B,S,KV,hd), "v": ...} (MLA: {"latent": (L,B,S,W)})
+    when ``return_cache``.
     """
     x = embeds if embeds is not None else layers.embed(params["embedding"], tokens)
     b, s = x.shape[0], x.shape[1]
     positions = jnp.arange(s)[None, :]
 
-    def body(carry, lp):
+    def body(carry, inp):
         x, aux = carry
+        lp = inp[0]
         h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, kv = attention_block(lp["attn"], h, cfg, positions, causal)
+        if cfg.is_mla:
+            attn_out, kv = mla.attention_block(lp["attn"], h, cfg, positions)
+        else:
+            attn_out, kv = attention_block(lp["attn"], h, cfg, positions,
+                                           causal)
         x = _residual_constraint(x + attn_out)
         h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        ffn_out, a = _ffn(lp["ffn"], h2, cfg)
+        ffn_out, a, _ = _ffn(lp["ffn"], h2, cfg)
         x = _residual_constraint(x + ffn_out)
         return (x, aux + a), kv if return_cache else None
 
     body = _remat(body, remat)
-    (x, aux), kv = layers.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                params["layers"])
+    (x, aux), kv = _scan_layers(body, (x, jnp.zeros((), jnp.float32)),
+                                params)
     logits = _logits(params, x, cfg)
     if return_cache:
-        cache = {"k": kv[0], "v": kv[1]}
+        cache = {"latent": kv} if cfg.is_mla else {"k": kv[0], "v": kv[1]}
         return logits, aux, cache
     return logits, aux
 
@@ -493,6 +629,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -
     from repro.distributed import hints
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     l = cfg.num_layers
+    if cfg.is_mla:
+        return {"latent": jnp.zeros((l, batch, max_seq, cfg.latent_width),
+                                    dtype)}
     if hints.get("kv_cache_dtype") == "int8":
         return {
             "k": jnp.zeros((l, batch, max_seq, kv, hd), jnp.int8),
@@ -513,14 +652,24 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     so a page's per-head ``(hd, page)`` slab is one tile of the paged
     kernels, held unpadded in the device's default layout (see
     :mod:`repro.kernels.paged_decode_attention`); rows address pages
-    through engine-side block tables. No int8 variant — the engine keeps
-    the contiguous cache under ``kv_cache_dtype`` hints."""
+    through engine-side block tables. MLA holds one latent head,
+    ``latent_pages`` ``(L, P, 1, W, page)``. An expert model's cache also
+    carries ``moe_counts``, int32 (picks, held picks, busiest held
+    expert's picks) summed by every step program. No int8 variant — the
+    engine keeps the contiguous cache under ``kv_cache_dtype`` hints."""
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     l = cfg.num_layers
-    return {
-        "k_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
-        "v_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
-    }
+    if cfg.is_mla:
+        cache = {"latent_pages": jnp.zeros(
+            (l, num_pages, 1, cfg.latent_width, page_size), dtype)}
+    else:
+        cache = {
+            "k_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
+            "v_pages": jnp.zeros((l, num_pages, kv, hd, page_size), dtype),
+        }
+    if cfg.is_moe:
+        cache["moe_counts"] = jnp.zeros((3,), jnp.int32)
+    return cache
 
 
 def prefill(params: dict, tokens: Array, cfg: ModelConfig, max_seq: int,
@@ -529,12 +678,14 @@ def prefill(params: dict, tokens: Array, cfg: ModelConfig, max_seq: int,
     logits, _, cache = forward(params, tokens, cfg, remat="none",
                                embeds=embeds, return_cache=True)
     s = tokens.shape[1] if tokens is not None else embeds.shape[1]
-    if max_seq > s:
-        pad = [(0, 0), (0, 0), (0, max_seq - s), (0, 0), (0, 0)]
-        cache = {k: jnp.pad(v.astype(jnp.bfloat16), pad) for k, v in cache.items()}
-    else:
-        cache = {k: v.astype(jnp.bfloat16) for k, v in cache.items()}
-    return logits, cache
+
+    def pad(v):
+        v = v.astype(jnp.bfloat16)
+        if max_seq <= s:
+            return v
+        return jnp.pad(v, [(0, 0), (0, 0), (0, max_seq - s)]
+                       + [(0, 0)] * (v.ndim - 3))
+    return logits, {k: pad(v) for k, v in cache.items()}
 
 
 def decode_step(params: dict, cache: dict, tokens: Array, lengths: Array,
@@ -548,35 +699,80 @@ def decode_step(params: dict, cache: dict, tokens: Array, lengths: Array,
     """
     x = layers.embed(params["embedding"], tokens)
     int8_kv = "k_scale" in cache
+    names = (("latent",) if cfg.is_mla else
+             ("k", "v", "k_scale", "v_scale") if int8_kv else ("k", "v"))
 
     def body(x, inp):
-        if int8_kv:
-            lp, kc, vc, ks, vs = inp
-        else:
-            lp, kc, vc = inp
-            ks = vs = None
+        lp, _, caches = inp
         h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, caches = attention_decode_block(lp["attn"], h, cfg,
-                                                  kc, vc, lengths, ks, vs,
-                                                  active=active)
+        if cfg.is_mla:
+            attn_out, lat = mla_chunk_block(lp["attn"], h, cfg, caches[0],
+                                            lengths, active)
+            caches = (lat,)
+        else:
+            kc, vc = caches[:2]
+            ks, vs = caches[2:] if int8_kv else (None, None)
+            attn_out, caches = attention_decode_block(
+                lp["attn"], h, cfg, kc, vc, lengths, ks, vs, active=active)
         x = x + attn_out
         h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
+        ffn_out, _, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active,
+                             serving=True)
         x = x + ffn_out
         return x, caches
 
-    if int8_kv:
-        x, (k_new, v_new, ks_new, vs_new) = layers.scan(
-            body, x, (params["layers"], cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": k_new, "v": v_new, "k_scale": ks_new,
-                     "v_scale": vs_new}
-    else:
-        x, (k_new, v_new) = layers.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": k_new, "v": v_new}
+    x, new = _scan_layers(body, x, params, tuple(cache[n] for n in names))
     logits = _logits(params, x, cfg)
-    return logits[:, 0], new_cache
+    return logits[:, 0], dict(zip(names, new))
+
+
+def _live(tokens: Array, active: Array | None, valid: Array | None):
+    """The (B, C) mask of a chunk's real tokens (the row is active, the
+    token not a pad under ``valid``), or ``active`` itself without
+    ``valid``."""
+    if valid is None:
+        return active
+    live = jnp.arange(tokens.shape[1])[None, :] < valid[:, None]
+    return live if active is None else live & active[:, None]
+
+
+def _scope(name: str, on: bool):
+    """A ``jax.named_scope`` where ``on``, so that a profile shows it."""
+    return jax.named_scope(name) if on else contextlib.nullcontext()
+
+
+def _paged_step(params: dict, cache: dict, tokens: Array, cfg: ModelConfig,
+                attend, active: Array | None):
+    """The layer scan of a paged step: the pools and the routing counters
+    ride the carry, each layer updating the pools in place at its own index
+    through ``attend(p, h, pools, layer) -> (out, pools)``, so a step moves
+    no pool-sized slab (with the pool donated, not one copy). Latent
+    attention and expert layers run under the named scopes ``mla`` and
+    ``moe``. Returns the final hidden states and the new cache."""
+    x = layers.embed(params["embedding"], tokens)
+    keys = _pool_keys(cfg)
+
+    def body(carry, inp):
+        x, pools, counts = carry
+        lp, l, _ = inp
+        h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        with _scope("mla", cfg.is_mla):
+            attn_out, pools = attend(lp["attn"], h, pools, l)
+        x = x + attn_out
+        h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
+        with _scope("moe", "router" in lp["ffn"]):
+            ffn_out, _, c = _ffn(lp["ffn"], h2, cfg, token_mask=active,
+                                 serving=True)
+        x = x + ffn_out
+        return (x, pools, counts + c), None
+
+    pools = tuple(cache[k] for k in keys)
+    (x, pools, counts), _ = _scan_layers(
+        body, (x, pools, jnp.zeros((3,), jnp.int32)), params)
+    new = dict(zip(keys, pools))
+    if "moe_counts" in cache:
+        new["moe_counts"] = cache["moe_counts"] + counts
+    return x, new
 
 
 def decode_step_paged(params: dict, cache: dict, tokens: Array,
@@ -584,30 +780,20 @@ def decode_step_paged(params: dict, cache: dict, tokens: Array,
                       cfg: ModelConfig, active: Array | None = None):
     """One decode step against the paged cache. tokens: (B,1); lengths:
     (B,); block_tables: (B, nb). Same contract as :func:`decode_step`
-    (logits (B,V), new cache; inactive rows untouched), with K/V written
-    into and gathered from the shared page pool. The layer scan carries the
-    whole pool and each layer updates it in place at its own index, so a
-    step moves no pool-sized slab (with the pool donated, not one copy)."""
-    x = layers.embed(params["embedding"], tokens)
-
-    def body(carry, inp):
-        x, kp, vp = carry
-        lp, l = inp
-        h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, (kp, vp) = attention_decode_block_paged(
-            lp["attn"], h, cfg, kp, vp, block_tables, lengths, active=active,
-            layer=l)
-        x = x + attn_out
-        h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
-        x = x + ffn_out
-        return (x, kp, vp), None
-
-    pool = (cache["k_pages"], cache["v_pages"])
-    (x, k_new, v_new), _ = layers.scan(
-        body, (x,) + pool,
-        (params["layers"], jnp.arange(pool[0].shape[0])))
-    return _logits(params, x, cfg)[:, 0], {"k_pages": k_new, "v_pages": v_new}
+    (logits (B,V), new cache; inactive rows untouched), with K/V (or the
+    latent rows) written into and gathered from the shared page pool by
+    :func:`_paged_step`."""
+    def attend(p, h, pools, layer):
+        if cfg.is_mla:
+            out, pool = mla_decode_block_paged(p, h, cfg, pools[0],
+                                               block_tables, lengths,
+                                               active=active, layer=layer)
+            return out, (pool,)
+        return attention_decode_block_paged(p, h, cfg, *pools, block_tables,
+                                            lengths, active=active,
+                                            layer=layer)
+    x, new = _paged_step(params, cache, tokens, cfg, attend, active)
+    return _logits(params, x, cfg)[:, 0], new
 
 
 def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
@@ -617,26 +803,18 @@ def prefill_chunk_paged(params: dict, cache: dict, tokens: Array,
     """Batched chunked prefill against the paged cache; see
     :func:`prefill_chunk` for the contract. The pool rides the layer scan's
     carry and is updated in place, as in :func:`decode_step_paged`."""
-    x = layers.embed(params["embedding"], tokens)
-
-    def body(carry, inp):
-        x, kp, vp = carry
-        lp, l = inp
-        h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, (kp, vp) = attention_prefill_chunk_block_paged(
-            lp["attn"], h, cfg, kp, vp, block_tables, start_len,
-            active=active, valid=valid, layer=l)
-        x = x + attn_out
-        h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
-        x = x + ffn_out
-        return (x, kp, vp), None
-
-    pool = (cache["k_pages"], cache["v_pages"])
-    (x, k_new, v_new), _ = layers.scan(
-        body, (x,) + pool,
-        (params["layers"], jnp.arange(pool[0].shape[0])))
-    return _logits(params, x, cfg), {"k_pages": k_new, "v_pages": v_new}
+    def attend(p, h, pools, layer):
+        if cfg.is_mla:
+            out, pool = mla_prefill_chunk_block_paged(
+                p, h, cfg, pools[0], block_tables, start_len, active=active,
+                valid=valid, layer=layer)
+            return out, (pool,)
+        return attention_prefill_chunk_block_paged(
+            p, h, cfg, *pools, block_tables, start_len, active=active,
+            valid=valid, layer=layer)
+    x, new = _paged_step(params, cache, tokens, cfg, attend,
+                         _live(tokens, active, valid))
+    return _logits(params, x, cfg), new
 
 
 def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
@@ -657,32 +835,30 @@ def prefill_chunk(params: dict, cache: dict, tokens: Array, start_len: Array,
     """
     x = layers.embed(params["embedding"], tokens)
     int8_kv = "k_scale" in cache
+    names = (("latent",) if cfg.is_mla else
+             ("k", "v", "k_scale", "v_scale") if int8_kv else ("k", "v"))
 
     def body(x, inp):
-        if int8_kv:
-            lp, kc, vc, ks, vs = inp
-        else:
-            lp, kc, vc = inp
-            ks = vs = None
+        lp, _, caches = inp
         h = layers.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        attn_out, caches = attention_prefill_chunk_block(
-            lp["attn"], h, cfg, kc, vc, start_len, ks, vs, active=active,
-            valid=valid)
+        if cfg.is_mla:
+            attn_out, lat = mla_chunk_block(lp["attn"], h, cfg, caches[0],
+                                            start_len, active, valid)
+            caches = (lat,)
+        else:
+            kc, vc = caches[:2]
+            ks, vs = caches[2:] if int8_kv else (None, None)
+            attn_out, caches = attention_prefill_chunk_block(
+                lp["attn"], h, cfg, kc, vc, start_len, ks, vs, active=active,
+                valid=valid)
         x = x + attn_out
         h2 = layers.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        ffn_out, _ = _ffn(lp["ffn"], h2, cfg, token_mask=active)
+        ffn_out, _, _ = _ffn(lp["ffn"], h2, cfg,
+                             token_mask=_live(tokens, active, valid),
+                             serving=True)
         x = x + ffn_out
         return x, caches
 
-    if int8_kv:
-        x, (k_new, v_new, ks_new, vs_new) = layers.scan(
-            body, x, (params["layers"], cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": k_new, "v": v_new, "k_scale": ks_new,
-                     "v_scale": vs_new}
-    else:
-        x, (k_new, v_new) = layers.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": k_new, "v": v_new}
+    x, new = _scan_layers(body, x, params, tuple(cache[n] for n in names))
     logits = _logits(params, x, cfg)
-    return logits, new_cache
+    return logits, dict(zip(names, new))

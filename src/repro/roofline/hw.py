@@ -96,6 +96,9 @@ def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
     fam = getattr(cfg, "family", "dense")
     if fam == "ssm":
         return 0
+    if getattr(cfg, "kv_lora_rank", 0):
+        # latent attention: one row of latent and shared rotary key a layer
+        return cfg.num_layers * cfg.latent_width * dtype_bytes
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     if fam == "hybrid":
         n_layers = cfg.num_layers // cfg.attn_every

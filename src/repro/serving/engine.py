@@ -17,8 +17,7 @@ interleaving). With the shipped policies:
   mixed      — stall-free mixed batching: the policy's ``step_budget`` hook
                returns a per-step (prefill_tokens, decode_tokens) split, so
                EVERY step advances decode; the prefill share is spread over
-               ALL mid-prefill slots and, where the family allows
-               (``ModelBundle.multi_slot_batchable``), dispatched as ONE
+               ALL mid-prefill slots and dispatched as ONE
                multi-slot ``prefill_chunk`` call with per-row ``valid``
                counts — ``prefill_dispatches`` drops by ~the mean number of
                concurrent prefills.
@@ -111,6 +110,10 @@ jitted programs carry the names of what they run (``decode_step``,
 remains to the next device dispatch), ``prefill_row_tokens`` (rows times
 width of every prefill dispatch, against the live ``prefill_tokens``) and
 ``kv_live_tokens``/``kv_pool_tokens`` (summed at each decode dispatch).
+An expert model's step programs sum its routing on the device, in the
+paged cache's ``moe_counts``; each decode step's fetch brings them back
+with the tokens, into ``expert_picks``, ``expert_picks_held`` and
+``expert_picks_peak``, at no extra sync.
 """
 from __future__ import annotations
 
@@ -158,6 +161,13 @@ class EngineStats:
     prefill_row_tokens: int = 0   # rows x width of every prefill dispatch
     kv_live_tokens: int = 0       # tokens live slots hold, per decode dispatch
     kv_pool_tokens: int = 0       # the pool's capacity, per decode dispatch
+    # ---- expert routing (an expert model's step programs sum it in the
+    # paged cache; each decode fetch reads it): routed picks of live tokens
+    # over all experts, those that land on the experts held here, and per
+    # dispatch and layer the busiest held expert's picks, summed
+    expert_picks: int = 0
+    expert_picks_held: int = 0
+    expert_picks_peak: int = 0
 
 
 class InferenceEngine:
@@ -263,8 +273,7 @@ class InferenceEngine:
                 raise ValueError(
                     f"family {self.cfg.family!r} cannot share prefixes: "
                     "its prefill state is not fully page-resident "
-                    "(slot-resident SSM state / cross-KV) or its numerics "
-                    "are batch-coupled (MoE capacity)")
+                    "(slot-resident SSM state / cross-KV)")
             self.prefix = PrefixCache(self.allocator) if prefix_cache else None
             self.cache = self.model.init_paged_cache(
                 kv_pages, page_size, max_slots, max_seq)
@@ -769,11 +778,10 @@ class InferenceEngine:
 
     def _prefill_batch(self, prefilling: list[int], budget: int) -> bool:
         """Budgeted prefill phase: advance EVERY mid-prefill slot under a
-        shared token budget, in ONE ``prefill_chunk`` dispatch when the
-        model allows it (``multi_slot_batchable``). Rows with shorter
-        pieces than the dispatch width are tail-padded and length-masked
-        via the per-row ``valid`` count, so each row's cache writes are
-        bit-identical to a solo prefill of the same piece.
+        shared token budget, in ONE ``prefill_chunk`` dispatch. Rows with
+        shorter pieces than the dispatch width are tail-padded and
+        length-masked via the per-row ``valid`` count, so each row's cache
+        writes are bit-identical to a solo prefill of the same piece.
 
         Cost stays per-row serialized (shared hardware serializes service
         demand), but ``prefill_dispatches`` counts actual dispatches — the
@@ -782,11 +790,9 @@ class InferenceEngine:
         plan = self._prefill_budget_plan(prefilling, budget)
         if not plan:
             return False
-        if len(plan) == 1 or not self.model.multi_slot_batchable():
-            # MoE routing couples rows through batch-level capacity: fall
-            # back to per-slot dispatches (same budget, same token grid)
-            for slot, c in plan:
-                self._prefill_slot(slot, self.active[slot], c)
+        if len(plan) == 1:
+            slot, c = plan[0]
+            self._prefill_slot(slot, self.active[slot], c)
             return True
         with TraceAnnotation("engine.prefill"):
             self._prefill_rows(plan)
@@ -1010,8 +1016,14 @@ class InferenceEngine:
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(self.lengths), jnp.asarray(mask))
         with TraceAnnotation("engine.sample"):
-            # the one host sync of the decode loop: fetch the argmaxes
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            # the one host sync of the decode loop: fetch the argmaxes (and
+            # an expert model's routing counters with them)
+            counts = self.cache.get("moe_counts") if self.paged else None
+            nxt, counts = jax.device_get((jnp.argmax(logits, axis=-1),
+                                          counts))
+        if counts is not None:
+            (self.stats.expert_picks, self.stats.expert_picks_held,
+             self.stats.expert_picks_peak) = (int(c) for c in counts)
         t_fetched = _time.perf_counter()
         self.stats.decode_syncs += 1
         with TraceAnnotation("engine.retire"):

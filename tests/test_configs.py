@@ -14,7 +14,7 @@ EXPECTED_PARAMS_B = {
     "jamba-v0.1-52b": (49.0, 54.0),
     "chameleon-34b": (32.0, 36.0),
     "seamless-m4t-large-v2": (1.8, 2.6),
-    "moonshot-v1-16b-a3b": (27.0, 31.0),   # assigned 48L spec (see DESIGN.md)
+    "moonshot-v1-16b-a3b": (15.5, 16.5),   # Moonlight-16B-A3B, 27 layers
     "kimi-k2-1t-a32b": (1000.0, 1090.0),
 }
 

@@ -80,7 +80,7 @@ def test_int8_kv_decode_close(rng_key):
 def test_moe_shardmap_falls_back_without_mesh(rng_key):
     """On a bare CPU (no mesh context) the shardmap impl must degrade to the
     scatter path and stay numerically identical."""
-    cfg = dataclasses.replace(CONFIGS["moonshot-v1-16b-a3b"].reduced(),
+    cfg = dataclasses.replace(CONFIGS["kimi-k2-1t-a32b"].reduced(),
                               capacity_factor=4.0)
     m = build_model(cfg)
     params = m.init(rng_key)
@@ -107,7 +107,7 @@ def test_moe_shardmap_matches_scatter_on_mesh():
         from repro.configs.registry import CONFIGS
         from repro.distributed import hints, sharding
         from repro.models.factory import build_model
-        cfg = dataclasses.replace(CONFIGS["moonshot-v1-16b-a3b"].reduced(),
+        cfg = dataclasses.replace(CONFIGS["kimi-k2-1t-a32b"].reduced(),
                                   capacity_factor=4.0)
         m = build_model(cfg)
         params = m.init(jax.random.key(0))

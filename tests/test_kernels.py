@@ -7,6 +7,9 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels import paged_mla_prefill_attention as mla_prefill_mod
+from repro.kernels.paged_mla_decode_attention import \
+    paged_mla_decode_attention
 from repro.kernels.paged_prefill_attention import paged_prefill_attention
 from repro.kernels.prefill_attention import prefill_attention
 from repro.kernels.rmsnorm import rmsnorm
@@ -379,3 +382,94 @@ def test_ops_interpret_backend_end_to_end(rng_key):
     finally:
         ops.set_backend(None)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=5e-5)
+
+
+# ------------------------------------------------- paged latent attention
+#: (heads, latent rank, rotary width, page, blocks, pool pages)
+MLA_SHAPES = [(4, 32, 8, 8, 4, 12), (16, 64, 16, 16, 3, 10)]
+
+
+def _latent_pool(key, shape, layer):
+    h, r, rp, page, nb, pool = shape
+    lead = () if layer is None else (2,)
+    return jax.random.normal(key, lead + (pool, 1, r + rp, page),
+                             jnp.bfloat16)
+
+
+@pytest.mark.parametrize("shape", MLA_SHAPES)
+@pytest.mark.parametrize("layer", [None, 1])
+def test_paged_mla_decode_attention(shape, layer, rng_key):
+    """The latent decode kernel (interpret) against its oracle, lengths
+    ending inside a page, on a page boundary and at one token; a 5-D pool
+    read at ``layer``."""
+    h, r, rp, page, nb, pool = shape
+    ks = jax.random.split(rng_key, 3)
+    q = jax.random.normal(ks[0], (4, h, r + rp))
+    pages = _latent_pool(ks[1], shape, layer)
+    tables = jax.random.permutation(ks[2], pool)[:4 * nb].reshape(4, nb) \
+        if pool >= 4 * nb else jax.random.randint(ks[2], (4, nb), 0, pool)
+    tables = tables.astype(jnp.int32)
+    lengths = jnp.asarray([1, page, page + 3, nb * page - 1], jnp.int32)
+    scale = 1.0 / np.sqrt(2 * rp + r)
+    out = paged_mla_decode_attention(
+        q, pages, tables, lengths, 0 if layer is None else layer,
+        scale=scale, rank=r, interpret=True)
+    slab = pages if layer is None else pages[layer]
+    want = ref.paged_mla_decode_attention_ref(q, slab, tables, lengths,
+                                              scale=scale, rank=r)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("shape", MLA_SHAPES)
+@pytest.mark.parametrize("c,tile", [(5, None), (6, 8), (3, 4)])
+def test_paged_mla_prefill_attention(shape, c, tile, rng_key, monkeypatch):
+    """The latent prefill kernel (interpret) against its oracle: chunks
+    starting inside a page and crossing into the next, several row tiles
+    with a padded last one (``tile``) and a single whole tile."""
+    h, r, rp, page, nb, pool = shape
+    if tile is not None:
+        monkeypatch.setattr(mla_prefill_mod, "ROW_TILE", tile)
+    ks = jax.random.split(rng_key, 3)
+    q = jax.random.normal(ks[0], (3, c * h, r + rp))
+    pages = _latent_pool(ks[1], shape, None)
+    tables = jax.random.randint(ks[2], (3, nb), 0, pool).astype(jnp.int32)
+    start = jnp.asarray([0, page - 2, nb * page - c], jnp.int32)
+    scale = 1.0 / np.sqrt(2 * rp + r)
+    kernel = mla_prefill_mod.paged_mla_prefill_attention.__wrapped__
+    out = kernel(q, pages, tables, start, heads=h, scale=scale, rank=r,
+                 interpret=True)
+    want = ref.paged_mla_prefill_attention_ref(q, pages, tables, start,
+                                               heads=h, scale=scale, rank=r)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_latent_model_interpret_backend_matches_jnp(rng_key):
+    """A latent-attention expert model's paged prefill and decode under the
+    interpret backend (the two latent kernels) == the jnp backend."""
+    import dataclasses
+
+    from repro.configs.registry import CONFIGS
+    from repro.kernels import ops
+    from repro.models.factory import build_model
+    cfg = dataclasses.replace(CONFIGS["moonshot-v1-16b-a3b"].reduced(),
+                              num_layers=2)
+    m = build_model(cfg)
+    params = m.init(rng_key)
+    toks = jax.random.randint(rng_key, (2, 11), 0, cfg.vocab_size)
+    bt = jnp.asarray(np.arange(1, 7, dtype=np.int32).reshape(2, 3))
+    out = {}
+    try:
+        for be in ("jnp", "interpret"):
+            ops.set_backend(be)
+            cache = m.init_paged_cache(7, 8, 2, 24)
+            lp, cache = m.prefill_chunk_paged(
+                params, cache, toks, jnp.zeros((2,), jnp.int32), bt)
+            ld, _ = m.decode_step_paged(params, cache, toks[:, -1:],
+                                        jnp.full((2,), 11, jnp.int32), bt)
+            out[be] = (np.asarray(lp, np.float32), np.asarray(ld, np.float32))
+    finally:
+        ops.set_backend(None)
+    for a, b in zip(out["jnp"], out["interpret"]):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)

@@ -88,16 +88,13 @@ PARITY_ARCHS = ["tinyllama-1.1b", "mamba2-1.3b", "jamba-v0.1-52b",
 def test_multi_slot_prefill_matches_sequential(arch, rng_key):
     """ONE prefill_chunk dispatch with per-row ``valid`` counts must match
     per-row sequential prefill (the legacy valid=None path) — logits at
-    each row's last real token AND the cache. Families that decline
-    multi-slot batching (``multi_slot_batchable() is False``) are skipped:
-    the engine never batches them."""
+    each row's last real token AND the cache, for every family the engine
+    batches (all of them: served expert layers drop no token)."""
     cfg = CONFIGS[arch].reduced()
     cfg = dataclasses.replace(cfg, num_layers=min(cfg.num_layers, 2))
     if cfg.family == "hybrid":   # period constraint: keep one full period
         cfg = CONFIGS[arch].reduced()
     m = build_model(cfg)
-    if not m.multi_slot_batchable():
-        pytest.skip(f"{arch}: family declines multi-slot batched prefill")
     params = m.init(rng_key)
     b, width, max_seq = 3, 5, 32
     counts = [5, 3, 2]           # per-row REAL chunk tokens, pads at tail
@@ -183,8 +180,8 @@ def test_mixed_stream_bit_identical_prefix_cache(tiny_model):
 
 @pytest.mark.parametrize("arch", ["mamba2-1.3b", "jamba-v0.1-52b"])
 def test_mixed_stream_bit_identical_families(arch, rng_key):
-    """SSM (multi-slot batchable) and hybrid (declines batching, falls back
-    to per-slot dispatch under the budget) both keep streams identical."""
+    """SSM and hybrid (expert sublayers served dropless) both keep streams
+    identical under multi-slot batched prefill."""
     cfg = CONFIGS[arch].reduced()
     cfg = dataclasses.replace(cfg, num_layers=min(cfg.num_layers, 2))
     if cfg.family == "hybrid":

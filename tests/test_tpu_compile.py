@@ -5,6 +5,7 @@ compiler about tiling, so a kernel can pass every parity test and still be
 refused on the chip. These tests lower each kernel with ``interpret=False``
 for one chip of a described ``v5e:2x2`` topology and compile it, at the
 registry's widths: stablelm-3b for the attention kernels and rmsnorm,
+moonshot-v1-16b-a3b (Moonlight) for the latent-attention kernels,
 mamba2-1.3b for the SSD scan. Nothing runs, so results and times are not
 checked here; the TPU compiler only has to accept the kernel.
 
@@ -21,12 +22,17 @@ from repro.configs.registry import CONFIGS
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.kernels.paged_mla_decode_attention import \
+    paged_mla_decode_attention
+from repro.kernels.paged_mla_prefill_attention import \
+    paged_mla_prefill_attention
 from repro.kernels.paged_prefill_attention import paged_prefill_attention
 from repro.kernels.prefill_attention import prefill_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_chunk_scan
 
 ATTN = CONFIGS["stablelm-3b"]
+MLA = CONFIGS["moonshot-v1-16b-a3b"]
 SSM = CONFIGS["mamba2-1.3b"]
 B, SEQ, CHUNK = 4, 256, 16
 
@@ -67,7 +73,18 @@ def _cases(page: int):
     pool = ((B * SEQ // page, kv, d, page), bf)
     table = ((B, SEQ // page), i32)
     q_len = SSM.ssm_chunk
+    mh, w, r = MLA.num_heads, MLA.latent_width, MLA.kv_lora_rank
+    latent = ((MLA.num_layers, B * SEQ // page, 1, w, page), bf)
+    scale = 1.0 / (MLA.qk_nope_head_dim + MLA.qk_rope_head_dim) ** 0.5
     return {
+        "paged_mla_decode_attention": (
+            lambda q, c, bt, ln: paged_mla_decode_attention(
+                q, c, bt, ln, 3, scale=scale, rank=r),
+            [((B, mh, w), f32), latent, table, ((B,), i32)]),
+        "paged_mla_prefill_attention": (
+            lambda q, c, bt, st: paged_mla_prefill_attention(
+                q, c, bt, st, 3, heads=mh, scale=scale, rank=r),
+            [((B, CHUNK * mh, w), f32), latent, table, ((B,), i32)]),
         "decode_attention": (
             lambda q, k, v, ln: decode_attention(q, k, v, ln,
                                                  rope_theta=theta),
@@ -103,7 +120,8 @@ def _cases(page: int):
     }
 
 
-PAGED = ("paged_decode_attention", "paged_prefill_attention")
+PAGED = ("paged_decode_attention", "paged_prefill_attention",
+         "paged_mla_decode_attention", "paged_mla_prefill_attention")
 # every kernel at a 16-token page; the paged kernels also at the smallest
 # page the autotuner offers and at the one it picks for the serving
 # launcher's 128-token window, both of which must compile for bf16 pools
@@ -128,12 +146,31 @@ def test_paged_step_updates_the_pool_in_place_on_v5e(program, one_chip,
     it, and no pool-sized copy lies between (the layer scan carries the
     pool, the kernels read it by layer, and its default layout is the one
     the kernels read)."""
+    _check_in_place(ATTN, program, "k_pages", one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_paged"])
+def test_latent_step_updates_the_pool_in_place_on_v5e(program, one_chip,
+                                                      no_compile_cache):
+    """As above for Moonlight's step programs at full width with its share
+    of 16 experts: the latent pool and the routing counters come in donated
+    and go out aliased, with no pool-sized copy between; the latent
+    attention kernels are in them."""
+    import dataclasses
+    text = _check_in_place(dataclasses.replace(MLA, experts_held=16),
+                           program, "latent_pages", one_chip)
+    kernel = ("paged_mla_decode_attention" if program == "decode_paged"
+              else "paged_mla_prefill_attention")
+    assert kernel in text
+
+
+def _check_in_place(cfg, program, pool_key, one_chip) -> str:
     import re
 
     from repro.kernels import ops
     from repro.models.factory import build_model
     from repro.serving.engine import InferenceEngine
-    model = build_model(ATTN)
+    model = build_model(cfg)
     pages, page, blocks = 8, 256, 4
     jitted = getattr(InferenceEngine(model, max_slots=1, max_seq=page,
                                      kv_pages=1, page_size=page),
@@ -159,11 +196,12 @@ def test_paged_step_updates_the_pool_in_place_on_v5e(program, one_chip,
     finally:
         ops.set_backend(None)
     assert "tpu_custom_call" in text
-    pool = "bf16[%d,%d,%d,%d,%d]" % shapes["k_pages"].shape
+    pool = "bf16[%d,%d,%d,%d,%d]" % shapes[pool_key].shape
     copies = [line[:120] for line in text.splitlines()
               if f"= {pool}" in line and re.search(r" copy(-start)?\(", line)]
     assert not copies
     n = len(jax.tree.leaves(params))         # the cache follows the params
     aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, \w+-alias\)",
                                           text.splitlines()[0])}
-    assert {n, n + 1} <= aliased
+    assert set(range(n, n + len(shapes))) <= aliased
+    return text
